@@ -158,4 +158,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.common.utils import init_compilation_cache
+    init_compilation_cache()
     sys.exit(main())

@@ -133,6 +133,8 @@ def run(seconds: float = 6.0, tenants: int = 16,
 
 
 if __name__ == "__main__":
+    from repro.common.utils import init_compilation_cache
+    init_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--seconds", type=float, default=6.0)
     ap.add_argument("--tenants", type=int, default=16)

@@ -187,6 +187,8 @@ def run(clients: int = 100, tenants: int = 20, seconds: float = 3.0,
 
 
 if __name__ == "__main__":
+    from repro.common.utils import init_compilation_cache
+    init_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=100,
                     help="well-behaved closed-loop client threads")

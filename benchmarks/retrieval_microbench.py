@@ -53,7 +53,7 @@ import numpy as np
 from repro.common.utils import count_compiles
 from repro.core.vector_index import VectorIndex
 from repro.kernels import ops, ref as kref
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, chip_peaks
 
 D = 256
 
@@ -244,8 +244,9 @@ def run_quick(csv_rows):
         flops = 2 * 64 * N * D
         bytes_ = (64 * D + N * D) * 4
         # v5e roofline for this op (exact MIPS is bandwidth-bound at Q=64)
-        t_compute = flops / PEAK_FLOPS_BF16
-        t_mem = bytes_ / HBM_BW
+        peaks = chip_peaks(V5E)
+        t_compute = flops / peaks.flops_bf16
+        t_mem = bytes_ / peaks.hbm_bw
         print(f"N={N:6d}: jnp_ref {t_ref*1e6:9.0f}us/call | v5e roofline "
               f"compute {t_compute*1e6:6.2f}us, memory {t_mem*1e6:6.2f}us "
               f"(bound: {'memory' if t_mem > t_compute else 'compute'})")
@@ -284,6 +285,8 @@ def run(csv_rows, steady: bool = False, quantized: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.common.utils import init_compilation_cache
+    init_compilation_cache()
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--steady", action="store_true",

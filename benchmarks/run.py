@@ -17,4 +17,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.common.utils import init_compilation_cache
+    init_compilation_cache()
     main()

@@ -138,6 +138,8 @@ def run(clients=(1, 2, 4, 8), seconds: float = 2.0, tenants: int = 8,
 
 
 if __name__ == "__main__":
+    from repro.common.utils import init_compilation_cache
+    init_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", default="1,2,4,8",
                     help="comma-separated client counts")

@@ -185,6 +185,8 @@ def run(csv_rows, use_kernel: bool = False, mode: str = "all",
 
 
 if __name__ == "__main__":
+    from repro.common.utils import init_compilation_cache
+    init_compilation_cache()
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--kernel", action="store_true",

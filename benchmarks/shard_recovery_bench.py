@@ -168,6 +168,8 @@ def run(seconds: float = 4.0, shards: int = 2, tenants: int = 8,
 
 
 if __name__ == "__main__":
+    from repro.common.utils import init_compilation_cache
+    init_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--seconds", type=float, default=4.0)
     ap.add_argument("--shards", type=int, default=2)

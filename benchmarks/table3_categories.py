@@ -23,4 +23,6 @@ def run(csv_rows):
 
 
 if __name__ == "__main__":
+    from repro.common.utils import init_compilation_cache
+    init_compilation_cache()
     run([])

@@ -161,6 +161,8 @@ def run(clients: int = 4, seconds: float = 0.5, pairs: int = 10,
 
 
 if __name__ == "__main__":
+    from repro.common.utils import init_compilation_cache
+    init_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=4)
     ap.add_argument("--seconds", type=float, default=0.5,

@@ -48,7 +48,7 @@ def main():
 
     data_dir = tempfile.mkdtemp(prefix="memori-agent-")
     service = MemoryService(
-        HashEmbedder(), budget=800, use_kernel=False,
+        HashEmbedder(), budget=800,
         data_dir=data_dir,
         policy=LifecyclePolicy(flush_interval_s=0.1, max_pending=128,
                                compact_tombstone_ratio=0.3,

@@ -28,7 +28,7 @@ def main():
     # and snapshot rotation — no manual flush() loops anywhere below
     policy = LifecyclePolicy(flush_interval_s=0.2, max_pending=64,
                              compact_tombstone_ratio=0.3)
-    memory = MemoryService(HashEmbedder(), budget=1300, use_kernel=False,
+    memory = MemoryService(HashEmbedder(), budget=1300,
                            policy=policy, data_dir=data_dir)
     full = FullContextMemory()
 
@@ -73,8 +73,7 @@ def main():
     # would normally be a fresh process — answers are bit-identical
     before = [memory.retrieve("demo/c0", q).text for q in QUESTIONS]
     memory.close()
-    recovered = MemoryService.recover(data_dir, HashEmbedder(),
-                                      use_kernel=False, budget=1300)
+    recovered = MemoryService.recover(data_dir, HashEmbedder(), budget=1300)
     after = [recovered.retrieve("demo/c0", q).text for q in QUESTIONS]
     print("\n--- durability ---")
     print(f"recovered from {data_dir}")

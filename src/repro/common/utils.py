@@ -4,6 +4,8 @@ from __future__ import annotations
 import dataclasses
 import logging
 import math
+import os
+import pathlib
 from typing import Any
 
 import jax
@@ -116,3 +118,17 @@ def log_bucket(x: float, buckets: int = 64) -> int:
     if x <= 0:
         return 0
     return min(buckets - 1, int(math.log2(x + 1)))
+
+
+def init_compilation_cache() -> str:
+    """Mount JAX's persistent compilation cache and return its directory.
+    Where `JAX_COMPILATION_CACHE_DIR` is set JAX reads it itself and this
+    sets nothing; otherwise the cache lives at `.jax_cache/` in the repo
+    root — a fixed path, because the path is part of what a later process
+    must find again.  Call before the first compilation."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

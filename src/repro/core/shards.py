@@ -39,6 +39,7 @@ import numpy as np
 
 from repro.common.utils import next_pow2
 from repro.core.vector_index import _search_device, sharded_topk
+from repro.kernels import ops as kops
 
 MIN_SHARD_CAPACITY = 64
 
@@ -323,7 +324,8 @@ class ShardedBank:
         else:
             s, i = _search_device(self._bank_dev, self._labels_dev, queries,
                                   q_ns, jnp.int32(self.n_slots), k=kk,
-                                  use_kernel=self.use_kernel, interpret=None,
+                                  use_kernel=self.use_kernel,
+                                  interpret=kops._interpret_default(),
                                   uniform=False)
         if kk < k:
             s = jnp.pad(s, ((0, 0), (0, k - kk)), constant_values=-jnp.inf)

@@ -208,7 +208,8 @@ def _rescore_exact(queries, cand_rows, cand_ids, *, k: int):
     score, (-inf, -1) padded — so the scores leaving a quantized index are
     exact, and quantization error only costs recall when a true top-k row
     falls outside the C-candidate pool."""
-    s = jnp.einsum("qd,qcd->qc", queries, cand_rows)
+    s = jnp.einsum("qd,qcd->qc", queries, cand_rows,
+                   precision=jax.lax.Precision.HIGHEST)
     s = jnp.where(cand_ids >= 0, s, _tm.NEG_INF)
     top_s, pos = jax.lax.top_k(s, k)
     top_i = jnp.take_along_axis(cand_ids, pos, axis=1)
@@ -705,21 +706,6 @@ class VectorIndex:
 # Distributed search (shard_map): used by launch/dryrun and on real meshes.
 # ---------------------------------------------------------------------------
 
-# jax moved shard_map out of experimental (and renamed check_rep->check_vma);
-# support both so the CPU-mesh parity tests run on older pinned jax too
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:                                    # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _shard_map_unchecked(fn, mesh, in_specs, out_specs):
-    import inspect
-    flag = "check_vma" if "check_vma" in \
-        inspect.signature(_shard_map).parameters else "check_rep"
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{flag: False})
-
-
 def sharded_topk(queries, bank, k: int, mesh: Mesh, axis_names=("data", "model"),
                  *, q_ns=None, bank_ns=None, use_kernel: bool = True,
                  interpret: Optional[bool] = None):
@@ -783,11 +769,10 @@ def sharded_topk(queries, bank, k: int, mesh: Mesh, axis_names=("data", "model")
     # outputs are replicated by construction (all_gather + local re-rank);
     # the replication checker can't prove it, so we assert it ourselves
     if masked:
-        fn = _shard_map_unchecked(local_masked, mesh=mesh,
-                                  in_specs=(P(), spec_bank, P(), spec_bank),
-                                  out_specs=(P(), P()))
+        fn = jax.shard_map(local_masked, mesh=mesh,
+                           in_specs=(P(), spec_bank, P(), spec_bank),
+                           out_specs=(P(), P()), check_vma=False)
         return fn(queries, bank, q_ns, bank_ns)
-    fn = _shard_map_unchecked(local, mesh=mesh,
-                              in_specs=(P(), spec_bank),
-                              out_specs=(P(), P()))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(), spec_bank),
+                       out_specs=(P(), P()), check_vma=False)
     return fn(queries, bank)

@@ -5,6 +5,9 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -2.0e38
+# the MIPS oracles contract at full f32 precision, like the kernels they
+# check (the TPU's default f32 dot rounds operands to bf16)
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def topk_mips_ref(queries, bank, k: int = 32, n_valid=None):
@@ -13,7 +16,7 @@ def topk_mips_ref(queries, bank, k: int = 32, n_valid=None):
     score NEG_INF and report index -1 — matching the kernel's stable-shape
     contract over capacity-padded banks."""
     s = jnp.einsum("qd,nd->qn", queries.astype(jnp.float32),
-                   bank.astype(jnp.float32))
+                   bank.astype(jnp.float32), precision=_EXACT)
     if n_valid is not None:
         col = jnp.arange(bank.shape[0], dtype=jnp.int32)[None, :]
         s = jnp.where(col < n_valid, s, NEG_INF)
@@ -44,7 +47,7 @@ def _quant_scores(queries, bank_i8, scales):
     `(q · row_i8) * scale`, not `q · (scale * row_i8)` — so oracle and
     kernel agree to the same rounding and index comparisons stay exact."""
     s = jnp.einsum("qd,nd->qn", jnp.asarray(queries, jnp.float32),
-                   jnp.asarray(bank_i8).astype(jnp.float32))
+                   jnp.asarray(bank_i8).astype(jnp.float32), precision=_EXACT)
     return s * jnp.asarray(scales, jnp.float32)[None, :]
 
 
@@ -83,7 +86,7 @@ def topk_mips_masked_ref(queries, bank, q_ns, bank_ns, k: int = 32,
     marking tombstoned rows.  `n_valid` bounds the live bank prefix of a
     capacity-padded bank, as in topk_mips_ref."""
     s = jnp.einsum("qd,nd->qn", queries.astype(jnp.float32),
-                   bank.astype(jnp.float32))
+                   bank.astype(jnp.float32), precision=_EXACT)
     ok = jnp.asarray(q_ns, jnp.int32)[:, None] == \
         jnp.asarray(bank_ns, jnp.int32)[None, :]
     if n_valid is not None:

@@ -49,6 +49,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0e38
+# full f32 contraction: the TPU's default f32 dot rounds its operands to
+# bf16, which would break exact parity with the host-side references
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _merge_topk(scores_ref, idx_ref, s, col, k: int):
@@ -83,7 +86,8 @@ def _kernel(nvalid_ref, q_ref, bank_ref, scores_ref, idx_ref, *, block_n: int,
     q = q_ref[...]
     b = bank_ref[...]
     s = jax.lax.dot_general(q, b, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)     # (Qb, Nb)
+                            preferred_element_type=jnp.float32,
+                            precision=_EXACT)                       # (Qb, Nb)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + nb * block_n
     s = jnp.where(col < nvalid_ref[0], s, NEG_INF)  # mask padded bank rows
     _merge_topk(scores_ref, idx_ref, s, col, k)
@@ -101,7 +105,8 @@ def _kernel_masked(nvalid_ref, q_ref, bank_ref, qns_ref, bns_ref, scores_ref,
     q = q_ref[...]
     b = bank_ref[...]
     s = jax.lax.dot_general(q, b, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)     # (Qb, Nb)
+                            preferred_element_type=jnp.float32,
+                            precision=_EXACT)                       # (Qb, Nb)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + nb * block_n
     # (Qb, 1) == (1, Nb) broadcast: a hit survives only within its namespace
     ok = (col < nvalid_ref[0]) & (qns_ref[...] == bns_ref[...])
@@ -124,7 +129,8 @@ def _kernel_quant(nvalid_ref, q_ref, bank_ref, scale_ref, scores_ref,
     # int8 tile directly (f32 accumulate on the MXU), then scale the score
     # columns; the f32 bank tile is never materialized
     s = jax.lax.dot_general(q, b.astype(jnp.float32), (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)     # (Qb, Nb)
+                            preferred_element_type=jnp.float32,
+                            precision=_EXACT)                       # (Qb, Nb)
     s = s * scale_ref[...]                           # (1, Nb) broadcast
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + nb * block_n
     s = jnp.where(col < nvalid_ref[0], s, NEG_INF)
@@ -144,7 +150,8 @@ def _kernel_quant_masked(nvalid_ref, q_ref, bank_ref, scale_ref, qns_ref,
     q = q_ref[...]
     b = bank_ref[...]                                # (Nb, D) int8
     s = jax.lax.dot_general(q, b.astype(jnp.float32), (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)     # (Qb, Nb)
+                            preferred_element_type=jnp.float32,
+                            precision=_EXACT)                       # (Qb, Nb)
     s = s * scale_ref[...]                           # fused dequant
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + nb * block_n
     ok = (col < nvalid_ref[0]) & (qns_ref[...] == bns_ref[...])

@@ -147,7 +147,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     if variant.startswith("serve_mesh"):
         # serving-specific mesh: model axis sized to divide the kv heads so
         # the decode cache shards cleanly (same 256 chips, different shape)
-        mesh = jax.make_mesh((32, 8), ("data", "model"))
+        mesh = mesh_lib.make_mesh((32, 8), ("data", "model"))
     else:
         mesh = mesh_lib.make_production_mesh(multi_pod=multi_pod)
     with mesh:

@@ -85,9 +85,10 @@ def extrapolate(cfg: ModelConfig, probe_cfgs: List[ModelConfig],
 
 def roofline_terms(per_chip_flops: float, per_chip_bytes: float,
                    per_chip_coll_bytes: float) -> Dict[str, float]:
-    compute_s = per_chip_flops / mesh_lib.PEAK_FLOPS_BF16
-    memory_s = per_chip_bytes / mesh_lib.HBM_BW
-    collective_s = per_chip_coll_bytes / mesh_lib.ICI_BW_PER_LINK
+    peaks = mesh_lib.chip_peaks(mesh_lib.V5E)
+    compute_s = per_chip_flops / peaks.flops_bf16
+    memory_s = per_chip_bytes / peaks.hbm_bw
+    collective_s = per_chip_coll_bytes / peaks.ici_bw_per_link
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dom = max(terms, key=terms.get)

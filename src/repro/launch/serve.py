@@ -35,16 +35,23 @@ rejections surface as HTTP 429 + Retry-After (see docs/OPERATIONS.md for
 tuning).  QoS needs the scheduler, so `--qos-*` requires --tick-interval.
 """
 import argparse
+import dataclasses
 import os
 import signal
+from typing import Any, Optional
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="memori-agent")
     ap.add_argument("--multipod", action="store_true")
     ap.add_argument("--host-demo", action="store_true")
     ap.add_argument("--max-len", type=int, default=128)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="PRNG seed of the agent model's random weights")
+    ap.add_argument("--quantize", choices=("none", "int8"), default="none",
+                    help="device bank residency: f32 rows, or int8 codes + "
+                         "per-row scales with exact f32 rescore")
     ap.add_argument("--snapshot-path", default=None,
                     help="durable directory for the lifecycle runtime "
                          "(rotating snapshots + WAL); recovered on boot, "
@@ -85,27 +92,45 @@ def main():
     ap.add_argument("--qos-max-queued-global", type=int, default=None,
                     help="global backlog cap; tenants above their "
                          "weight-proportional fair share are shed first")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.snapshot_interval is not None and args.snapshot_path is None:
         ap.error("--snapshot-interval needs --snapshot-path (rotation "
                  "without a durable directory would silently no-op)")
     if args.http_port is not None and not args.api_keys:
         ap.error("--http-port needs --api-keys (an unauthenticated frontend "
                  "would serve every tenant's memory to anyone)")
-    wants_qos = (args.qos_rate is not None or args.qos_max_queued is not None
-                 or args.qos_max_queued_global is not None)
-    if wants_qos and args.tick_interval is None:
+    if args.quantize != "none" and args.snapshot_path is not None:
+        ap.error("--quantize needs a fresh bank: recovery from "
+                 "--snapshot-path restores the f32 device bank")
+    if _wants_qos(args) and args.tick_interval is None:
         ap.error("--qos-* flags need --tick-interval (admission control "
                  "lives in the scheduler's submit path)")
+    return args
 
-    if args.host_demo:
-        os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
-                                   + os.environ.get("XLA_FLAGS", ""))
 
+def _wants_qos(args) -> bool:
+    return (args.qos_rate is not None or args.qos_max_queued is not None
+            or args.qos_max_queued_global is not None)
+
+
+@dataclasses.dataclass
+class Server:
+    """What `build_server` assembles: the agent engine, the memory service
+    (with its scheduler mounted when `--tick-interval` is given) and the
+    HTTP frontend (unstarted; None without `--http-port`)."""
+    engine: Any
+    service: Any
+    frontend: Optional[Any] = None
+
+
+def build_server(args: argparse.Namespace) -> Server:
+    """Build the served stack from parsed flags: the agent model and its
+    engine, the memory service (recovered from `--snapshot-path` when
+    given), its MemoryScheduler and its HTTP frontend."""
     import jax
 
     from repro.configs import get_config
-    from repro.core import LifecyclePolicy, MemoriClient, MemoryService
+    from repro.core import LifecyclePolicy, MemoryService
     from repro.core.embedder import HashEmbedder
     from repro.data.tokenizer import HashTokenizer
     from repro.models.model_api import Model
@@ -116,7 +141,7 @@ def main():
     if args.host_demo:
         cfg = cfg.reduced(layers=2, d_model=128)
     model = Model(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
+    params = model.init_params(jax.random.PRNGKey(args.seed))
     tok = HashTokenizer(cfg.vocab_size)
     engine = Engine(model, params, max_len=args.max_len, slots=2,
                     sampler=SamplerConfig(temperature=0.8, top_k=40),
@@ -139,18 +164,18 @@ def main():
                 "directory (restore the file once via "
                 "MemoryService.restore, then serve with a directory)")
         service = MemoryService.recover(
-            args.snapshot_path, HashEmbedder(), policy=policy,
-            use_kernel=False, budget=800)
+            args.snapshot_path, HashEmbedder(), policy=policy, budget=800)
         print(f"recovered memory store from {args.snapshot_path}: "
               f"{service.stats()}")
     else:
-        service = MemoryService(HashEmbedder(), budget=800, use_kernel=False,
+        service = MemoryService(HashEmbedder(), budget=800,
+                                quantize=args.quantize,
                                 policy=policy if wants_runtime else None)
     if args.tick_interval is not None:
         # every handler / SDK client request from here on coalesces with
         # its concurrent peers into one batched launch per scheduler tick
         admission = None
-        if wants_qos:
+        if _wants_qos(args):
             from repro.core import AdmissionPolicy, TenantPolicy
             admission = AdmissionPolicy(
                 default=TenantPolicy(rate=args.qos_rate,
@@ -160,6 +185,27 @@ def main():
         service.start_scheduler(tick_interval_s=args.tick_interval,
                                 max_batch=args.max_batch,
                                 admission=admission)
+    frontend = None
+    if args.http_port is not None:
+        from repro.serving.frontend import MemoryFrontend
+        keys = dict(pair.split("=", 1) for pair in args.api_keys.split(","))
+        frontend = MemoryFrontend(service, keys, host=args.http_host,
+                                  port=args.http_port)
+    return Server(engine=engine, service=service, frontend=frontend)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    if args.host_demo:
+        os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
+                                   + os.environ.get("XLA_FLAGS", ""))
+
+    from repro.common.utils import init_compilation_cache
+    from repro.core import MemoriClient
+
+    init_compilation_cache()
+    server = build_server(args)
+    engine, service, frontend = server.engine, server.service, server.frontend
 
     def _shutdown(signum, frame):
         # container shutdown: unwind via SystemExit (flush's all-or-nothing
@@ -175,16 +221,10 @@ def main():
     llm = lambda p: engine.generate([p[-500:]], max_new_tokens=12)[0]  # noqa: E731
     client = MemoriClient(llm, service.namespace("u0/demo"))
 
-    frontend = None
     try:
-        if args.http_port is not None:
-            from repro.serving.frontend import MemoryFrontend
-            keys = dict(pair.split("=", 1)
-                        for pair in args.api_keys.split(","))
-            frontend = MemoryFrontend(service, keys, host=args.http_host,
-                                      port=args.http_port)
+        if frontend is not None:
             print(f"memory layer serving on {frontend.address} "
-                  f"({len(keys)} api keys)")
+                  f"({len(frontend.api_keys)} api keys)")
             frontend.serve_forever()       # until SIGTERM/SIGINT
         else:
             print(client.chat("I work as a translator and I live in Cusco."))

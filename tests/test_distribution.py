@@ -52,6 +52,17 @@ def test_param_specs_shardable_on_production_shape():
                 assert dim % size == 0, (arch, s.shape, p)
 
 
+def test_meshes_have_auto_axes_and_peaks_are_keyed_by_device_kind():
+    import jax
+    from repro.launch import mesh as mesh_lib
+    mesh = make_host_mesh(1, 1)
+    assert set(mesh.axis_types) == {jax.sharding.AxisType.Auto}
+    v5e = mesh_lib.chip_peaks("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        mesh_lib.chip_peaks("cpu")
+
+
 # (sharded_topk parity moved to tests/test_distributed_parity.py, which
 # also covers the k > shard_rows edge and the Pallas-kernel comparison)
 
@@ -66,8 +77,9 @@ def test_dryrun_smoke_subprocess():
         import dataclasses, jax
         from repro.configs import get_config
         from repro.launch.sharding import build_step
+        from repro.launch.mesh import make_mesh
         from repro.models.config import INPUT_SHAPES
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         for arch in ("internlm2-1.8b", "mamba2-2.7b", "phi3.5-moe-42b-a6.6b"):
             cfg = get_config(arch).reduced()
             for sh_name, bat, sq in (("train_4k", 8, 64), ("decode_32k", 8, 64)):

@@ -222,6 +222,7 @@ def test_sharded_bank_spans_all_mesh_devices_with_parity():
         import jax
         from repro.core import MemoryService, Message
         from repro.core.embedder import HashEmbedder
+        from repro.launch.mesh import make_mesh
 
         cities = ["Tallinn", "Porto", "Cusco", "Oslo", "Quito", "Hanoi",
                   "Lagos", "Lima"]
@@ -233,7 +234,7 @@ def test_sharded_bank_spans_all_mesh_devices_with_parity():
             svc.flush()
             return svc
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         svc = fill(MemoryService(HashEmbedder(), use_kernel=False,
                                  budget=800, shards=8, mesh=mesh))
         queries = [("u%d/c0" % i, "Which city does the user live in?")
