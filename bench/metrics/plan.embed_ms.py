@@ -1,0 +1,7 @@
+"""Embed stage: mean duration of the window's `plan.embed` spans, one per
+tick's retrieve batch (ms)."""
+
+
+def read(obs):
+    d = [s["dur_s"] for s in obs.spans if s["name"] == "plan.embed"]
+    return 1e3 * sum(d) / len(d) if d else None
