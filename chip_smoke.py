@@ -425,7 +425,7 @@ def phase_serve(quantize: str, *, bulk_rows: int = BULK_ROWS,
                  np.tile(np.float32(GRAPH_KNOBS["edge_weights"]), (BATCH, 1)),
                  np.full(BATCH, GRAPH_KNOBS["hops"], np.int32), k=pool_k,
                  max_hops=GRAPH_KNOBS["hops"], seed_k=plan.graph_seed_k,
-                 decay=plan.graph_decay)[:2]
+                 decay=plan.graph_decay)
         for i in range(0, len(probe), BATCH)])]
     dev_ids, dev_scores = (np.concatenate(a) for a in dev_graph)
     n_graph_rows = 0

@@ -136,8 +136,7 @@ def _expand_device(edge_src, edge_dst, edge_type, edge_w, node_ns,
     (`n_edges`, `n_rows`) and the executable is keyed only on the pow2 lane
     capacities and the (hops, k, seed_k) bucket — appends within a capacity
     bucket reuse it.  Returns (row ids (B, kk) i32 best-first -1-padded,
-    scores (B, kk) f32, frontier sizes (hops,) i32, edges touched (hops,)
-    i32).  Float32 op order here is the oracle contract — see
+    scores (B, kk) f32).  Float32 op order here is the oracle contract — see
     kernels/ref.graph_expand_ref, which mirrors it expression by
     expression."""
     B = q_ns.shape[0]
@@ -178,7 +177,6 @@ def _expand_device(edge_src, edge_dst, edge_type, edge_w, node_ns,
     deg_f = jnp.maximum(deg, 1).astype(jnp.float32)
     we = type_w[:, jnp.clip(edge_type, 0, N_EDGE_TYPES - 1)] \
         * edge_w[None, :]                                 # (B, Ecap)
-    frontier_sizes, edges_touched = [], []
     for h in range(1, hops + 1):
         c = F[:, src_c] * we          # float32 op order = oracle contract
         c = c * decay32
@@ -191,8 +189,6 @@ def _expand_device(edge_src, edge_dst, edge_type, edge_w, node_ns,
         newF = jnp.where(live, newF, 0.0)
         acc = jnp.maximum(acc, newF)
         F = newF
-        edges_touched.append(jnp.sum((c > 0).astype(jnp.int32)))
-        frontier_sizes.append(jnp.sum((newF > 0).astype(jnp.int32)))
     # -- node activations -> row ranking ------------------------------------
     acc = jnp.where(seed_mask, 0.0, acc)
     r_idx = jnp.arange(Rcap, dtype=jnp.int32)
@@ -213,8 +209,7 @@ def _expand_device(edge_src, edge_dst, edge_type, edge_w, node_ns,
     kk = min(k, Rcap)
     alive = neg_s[:, :kk] < jnp.inf
     return (jnp.where(alive, ids_s[:, :kk], -1),
-            jnp.where(alive, -neg_s[:, :kk], 0.0),
-            jnp.stack(frontier_sizes), jnp.stack(edges_touched))
+            jnp.where(alive, -neg_s[:, :kk], 0.0))
 
 
 class GraphInvariantError(RuntimeError):
@@ -485,14 +480,13 @@ class MemoryGraph:
         surface).  `type_w` (B, 3) f32 per-request edge-type weights,
         `hops_b` (B,) i32 per-request hop counts (0 = seeds only).
         `max_hops` is the static unrolled depth (pow2-bucketed by the
-        caller); `k` the ranking width.  Returns (ids (B, k) i32 device,
-        scores (B, k) f32 device, per-hop frontier sizes, per-hop edges
-        touched — both small host lists)."""
+        caller); `k` the ranking width.  Returns (ids (B, k) i32, scores
+        (B, k) f32), both on the device: nothing is read to the host."""
         self._ensure_device()
         self.sync_device()
         d = self._dev
         hops = max(1, int(max_hops))
-        ids, scores, fsz, etc = _expand_device(
+        ids, scores = _expand_device(
             d["edge_src"], d["edge_dst"], d["edge_type"], d["edge_w"],
             d["node_ns"], d["row_sub"], d["row_obj"], row_labels,
             tuple(jnp.asarray(r, jnp.int32) for r in rankings),
@@ -506,8 +500,7 @@ class MemoryGraph:
             ids = jnp.pad(ids, ((0, 0), (0, k - ids.shape[1])),
                           constant_values=-1)
             scores = jnp.pad(scores, ((0, 0), (0, k - scores.shape[1])))
-        return ids, scores, [int(x) for x in np.asarray(fsz)], \
-            [int(x) for x in np.asarray(etc)]
+        return ids, scores
 
     # -- compaction / persistence -------------------------------------------
     def compact_rows(self, old_to_new: np.ndarray) -> None:

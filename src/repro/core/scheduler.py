@@ -277,9 +277,8 @@ class MemoryScheduler:
         stack = contextlib.ExitStack()
         if batch_traces:
             stack.enter_context(tel.activate(batch_traces))
-            stack.enter_context(tel.span("scheduler.tick",
-                                         batch_size=len(batch),
-                                         grouped=grouped))
+        stack.enter_context(tel.span("scheduler.tick", batch_size=len(batch),
+                                     grouped=grouped))
         try:
             with group as ginfo:
                 i = 0
@@ -358,9 +357,11 @@ class MemoryScheduler:
         finally:
             stack.close()
         # futures resolve only after the (possibly grouped) WAL writes are
-        # durable — a client never observes an ack for a lost write
-        for fut, resp in resolutions:
-            self._resolve(fut, resp)
+        # durable — a client never observes an ack for a lost write.  Each
+        # wakes a waiting handler thread, which then contends for the GIL.
+        with tel.span("scheduler.resolve", requests=len(resolutions)):
+            for fut, resp in resolutions:
+                self._resolve(fut, resp)
         # counters mutate under the condition lock: stats() snapshots under
         # the same lock, so /v1/stats never reports a torn view of a tick
         with self._cv:
@@ -442,7 +443,9 @@ class MemoryScheduler:
     def _loop(self) -> None:
         self._thread_ident = threading.get_ident()
         while True:
-            with self._cv:
+            # everything between two ticks: the wait for a first arrival,
+            # the micro-batch window and the drain
+            with get_telemetry().span("scheduler.wait"), self._cv:
                 while not self.admission.total_queued and not self._closed:
                     self._cv.wait()
                 if self._closed and not self.admission.total_queued:

@@ -81,8 +81,8 @@ from repro.core.store import MemoryStore
 from repro.core.summaries import Summary
 from repro.core.triples import Triple
 from repro.data.tokenizer import HashTokenizer
-from repro.obs.telemetry import (GRAPH_EXPAND_LATENCY, RECORD_LATENCY,
-                                 RETRIEVE_LATENCY, get_telemetry)
+from repro.obs.telemetry import (RECORD_LATENCY, RETRIEVE_LATENCY,
+                                 get_telemetry)
 
 # graph-stage fallbacks when neither the request nor the plan sets them:
 # 2 hops reaches friend-of-a-fact chains, causal/temporal edges slightly
@@ -468,7 +468,8 @@ class MemoryService:
                                 sp.set(host_fallbacks=len(fb))
                                 _, hi = vindex.search_host(
                                     qmat[fb], q_ns[fb], k=self.pool)
-                                dense_ids = np.asarray(dense_ids).copy()
+                                with tel.span("device.wait"):
+                                    dense_ids = np.asarray(dense_ids).copy()
                                 dense_ids[fb] = hi
                                 for i in fb:
                                     tiers.note_host_fallback(tenants[i].ns_id)
@@ -507,7 +508,6 @@ class MemoryService:
                                for r, d in zip(res, downed)]
                 if any(graph_wants) and rankings:
                     g = self.store.graph
-                    t_g = time.perf_counter()
                     hops_list = [rr.hops if w else 0
                                  for rr, w in zip(res, graph_wants)]
                     hops_arr = np.zeros((Bp,), np.int32)
@@ -518,7 +518,7 @@ class MemoryService:
                     with tel.span("plan.graph", batch=Bp, pool=self.pool,
                                   hops_compiled=max_hops,
                                   launches=1) as sp:
-                        graph_ids, _, fsz, etc = g.expand(
+                        graph_ids, _ = g.expand(
                             rankings, q_ns,
                             self.store.row_namespaces_device(), tw,
                             hops_arr, k=self.pool, max_hops=max_hops,
@@ -526,19 +526,10 @@ class MemoryService:
                             decay=plan.graph_decay)
                         graph_ids = self._mask_ranking(
                             graph_ids, graph_wants, Bp)
-                        sp.set(frontier_sizes=fsz, edges_touched=etc,
-                               nodes=g.n_nodes, edges=g.n_edges)
+                        sp.set(nodes=g.n_nodes, edges=g.n_edges)
                     rankings.append(graph_ids)
                     weight_cols.append(
                         [r.graph_weight for r in res] + [0.0] * (Bp - B))
-                    tel.inc("memori_graph_expansions", 1,
-                            help="batched k-hop expansion launches")
-                    tel.inc("memori_graph_requests",
-                            sum(graph_wants),
-                            help="requests whose plan ran the graph stage")
-                    tel.observe(GRAPH_EXPAND_LATENCY,
-                                time.perf_counter() - t_g,
-                                help="graph k-hop expansion stage latency")
                 with tel.span("plan.fuse", batch=Bp, k=k_fuse,
                               rankings=len(rankings), launches=1):
                     fused_ids, fused_scores = rrf_fuse_batch(
@@ -547,6 +538,9 @@ class MemoryService:
                             [np.asarray(c, np.float32) for c in weight_cols],
                             axis=1),
                         k=k_fuse)
+                # the first host read of the tick's device work: the
+                # stages above only dispatched it
+                with tel.span("device.wait"):
                     fused_ids = np.asarray(fused_ids)[:B]
                     fused_scores = np.asarray(fused_scores)[:B]
             else:

@@ -40,6 +40,7 @@ import numpy as np
 from repro.common.utils import next_pow2
 from repro.core.vector_index import _search_device, sharded_topk
 from repro.kernels import ops as kops
+from repro.obs.telemetry import get_telemetry
 
 MIN_SHARD_CAPACITY = 64
 
@@ -330,6 +331,8 @@ class ShardedBank:
         if kk < k:
             s = jnp.pad(s, ((0, 0), (0, k - kk)), constant_values=-jnp.inf)
             i = jnp.pad(i, ((0, 0), (0, k - kk)), constant_values=-1)
+        with get_telemetry().span("device.wait"):
+            i = np.asarray(i)
         return s, self.slots_to_rows(i)
 
     def slots_to_rows(self, slot_ids) -> np.ndarray:

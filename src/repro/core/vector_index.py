@@ -60,6 +60,7 @@ from repro.common.utils import next_pow2 as _next_pow2
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.kernels import topk_mips as _tm
+from repro.obs.telemetry import get_telemetry
 
 
 # ---------------------------------------------------------------------------
@@ -621,18 +622,23 @@ class VectorIndex:
             self._bank_dev, self._scales_dev, labels, queries, q_ns,
             jnp.int32(self.n), k=kc, use_kernel=self.use_kernel,
             interpret=kops._interpret_default(), uniform=uniform)
-        i_host = np.asarray(i)                       # (Q, C) candidate ids
-        cand = self._bank[np.clip(i_host, 0, self.capacity - 1)]
-        s, i = _rescore_exact(queries, jnp.asarray(cand),
-                              jnp.asarray(i_host), k=kk)
-        self.counters["quant_searches"] += 1
-        i_np = np.asarray(i)                         # small (Q, k) D2H
-        firstk = i_host[:, :kk]
-        for r in range(i_np.shape[0]):
-            fin = i_np[r][i_np[r] >= 0]
-            self.counters["rescore_rows"] += int(fin.size)
-            self.counters["rescore_hits"] += int(np.isin(fin,
-                                                         firstk[r]).sum())
+        tel = get_telemetry()
+        # the rescore's host work; its two device reads are timed apart
+        with tel.span("dense.rescore"):
+            with tel.span("device.wait"):
+                i_host = np.asarray(i)               # (Q, C) candidate ids
+            cand = self._bank[np.clip(i_host, 0, self.capacity - 1)]
+            s, i = _rescore_exact(queries, jnp.asarray(cand),
+                                  jnp.asarray(i_host), k=kk)
+            self.counters["quant_searches"] += 1
+            with tel.span("device.wait"):
+                i_np = np.asarray(i)                 # small (Q, k) D2H
+            firstk = i_host[:, :kk]
+            for r in range(i_np.shape[0]):
+                fin = i_np[r][i_np[r] >= 0]
+                self.counters["rescore_rows"] += int(fin.size)
+                self.counters["rescore_hits"] += int(np.isin(
+                    fin, firstk[r]).sum())
         return s, i, kk
 
     def _to_host(self, s, i, k: int, kk: int):

@@ -26,16 +26,44 @@ from three primitives:
   optional JSONL file sink): slow queries over a configurable threshold,
   admission rejections, degraded-shard responses, backpressure, recovery.
 
+Every `span()` is also mirrored into the profiler trace: while the
+registry is enabled it opens a `jax.profiler.TraceAnnotation` of the same
+name on the calling thread, with or without an active request trace, so a
+profile captured around live traffic (`jax.profiler.trace`) shows the
+program's spans — `scheduler.wait`, `scheduler.tick`, `plan.*`,
+`device.wait`, `scheduler.resolve`, `frontend`, `frontend.respond` —
+above the device ops.  Attributes ride along only while a profiler
+session is recording, and with no session the mirror is one flag check.
+Two process-wide hooks cover host work that no request owns:
+
+* `gc.pause` — `gc.callbacks` time every collection on the thread that
+  runs it (annotation with the generation, plus the
+  `memori_gc_pause_seconds` histogram).  A collection belongs to the
+  process, not to a request, and fires at arbitrary allocation points
+  (inside this module's own bookkeeping too, under its locks), so it
+  records into no request tree and takes no lock: the pause is queued on
+  the histogram (`DeferredHistogram.defer`) and folded in at the next
+  read.  Only the process-wide registry gets the hook's data, so only a
+  registry that `set_telemetry` installs exports the histogram.
+* `jit.compile` — one `jax.monitoring` listener counts every XLA backend
+  compile (`memori_jit_compiles`) and back-dates a `jit.compile` span into
+  the compiling thread's active request trees.  The profile already holds
+  JAX's own compile events.  The listener is registered with the first
+  span (where jax is first imported), so `repro.obs` stays importable
+  without jax.
+
 Everything hangs off one process-wide registry (`get_telemetry()`);
 `set_telemetry(Telemetry(enabled=False))` turns the whole layer into
-no-ops (the overhead bench's baseline).  The registry never calls out
-under its locks and never blocks, so it is safe to use inside the
-lifecycle runtime's lock, the scheduler tick, and the WAL append path.
+no-ops (the overhead bench's baseline): no spans, no annotations, no
+hook observations.  The registry never calls out under its locks and
+never blocks, so it is safe to use inside the lifecycle runtime's lock,
+the scheduler tick, and the WAL append path.
 """
 from __future__ import annotations
 
 import bisect
 import contextlib
+import gc
 import json
 import threading
 import time
@@ -50,12 +78,19 @@ RETRIEVE_LATENCY = "memori_retrieve_latency_seconds"
 RECORD_LATENCY = "memori_record_latency_seconds"
 FLUSH_LATENCY = "memori_flush_latency_seconds"
 FSYNC_LATENCY = "memori_fsync_latency_seconds"
-GRAPH_EXPAND_LATENCY = "memori_graph_expand_latency_seconds"
+GC_PAUSE = "memori_gc_pause_seconds"
+JIT_COMPILES = "memori_jit_compiles"
+# the event jax.monitoring reports once per XLA backend compile
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 # 100us .. 10s: wide enough for a CPU dev box and a production accelerator
 # without reconfiguration; override per-histogram via buckets=
 DEFAULT_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                    0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
+# 10us .. 2.5s: a young collection takes microseconds, a full one over a
+# large heap can take a tenth of a second or more
+GC_BUCKETS = (0.00001, 0.0001, 0.001, 0.005, 0.01, 0.025, 0.05, 0.1,
+              0.25, 0.5, 1.0, 2.5)
 
 
 def _fmt(v: float) -> str:
@@ -144,6 +179,36 @@ class Histogram:
         lines.append(f"{self.name}_sum {_fmt(total)}")
         lines.append(f"{self.name}_count {int(cum[-1])}")
         return lines
+
+
+class DeferredHistogram(Histogram):
+    """A histogram that a `gc.callbacks` hook feeds.  A collection can
+    start on a thread that already holds this histogram's lock (a scrape
+    allocates inside `snapshot()`), so `defer()` takes no lock: it appends
+    to a deque (atomic under the GIL), and every `snapshot()` folds the
+    queued values in under the lock."""
+
+    __slots__ = ("_pending",)
+
+    def __init__(self, name: str, help: str = "",
+                 buckets: Sequence[float] = DEFAULT_BUCKETS):
+        super().__init__(name, help, buckets)
+        self._pending: deque = deque()
+
+    def defer(self, value: float) -> None:
+        self._pending.append(value)
+
+    def snapshot(self) -> Tuple[np.ndarray, float]:
+        with self._lock:
+            while self._pending:
+                v = self._pending.popleft()
+                self._counts[bisect.bisect_left(self.buckets, v)] += 1
+                self._sum += v
+            return self._counts.copy(), float(self._sum)
+
+    @property
+    def count(self) -> int:
+        return int(self.snapshot()[0].sum())
 
 
 class Span:
@@ -356,24 +421,30 @@ class Telemetry:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        """A timed child span in every active trace (no-op with none
-        active — the duration is measured either way only if someone is
-        listening: zero perf_counter calls when disabled)."""
+        """A timed child span in every active trace, mirrored into the
+        profiler trace as an annotation of the same name whether or not a
+        trace is active (zero perf_counter calls and no annotation when
+        disabled)."""
         if not self.enabled:
             yield _NULL_HANDLE
             return
-        active = getattr(self._tls, "active", None)
-        if not active:
-            yield _NULL_HANDLE
-            return
-        opened = [(tr, tr.push(name, dict(attrs))) for tr in active]
-        t0 = time.perf_counter()
+        ann = _annotate(name, attrs)
         try:
-            yield _SpanHandle(tuple(sp for _, sp in opened))
+            active = getattr(self._tls, "active", None)
+            if not active:
+                yield _NULL_HANDLE
+                return
+            opened = [(tr, tr.push(name, dict(attrs))) for tr in active]
+            t0 = time.perf_counter()
+            try:
+                yield _SpanHandle(tuple(sp for _, sp in opened))
+            finally:
+                dt = time.perf_counter() - t0
+                for tr, sp in opened:
+                    tr.pop(sp, dt)
         finally:
-            dt = time.perf_counter() - t0
-            for tr, sp in opened:
-                tr.pop(sp, dt)
+            if ann is not None:
+                ann.__exit__(None, None, None)
 
     def finish_trace(self, trace: Optional[Trace]) -> None:
         """Close a trace and push it into the ring buffer (oldest traces
@@ -440,6 +511,88 @@ class Telemetry:
                 self._sink = None
 
 
+# -- the profiler mirror and the process-wide hooks --------------------------
+_jax_lock = threading.Lock()
+_annotation_cls: Any = None        # TraceAnnotation; False without jax
+_gc_tls = threading.local()
+
+
+def _profiler_annotation():
+    """`jax.profiler.TraceAnnotation`, imported on first use; registers
+    the compile listener at the same time (once per process).  False when
+    jax is not installed."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        with _jax_lock:
+            if _annotation_cls is None:
+                try:
+                    from jax import monitoring
+                    from jax.profiler import TraceAnnotation
+                except ImportError:
+                    _annotation_cls = False
+                else:
+                    monitoring.register_event_duration_secs_listener(
+                        _on_jax_duration)
+                    _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
+def _annotate(name: str, attrs: dict):
+    """An entered annotation while a profiler session records, else None
+    (one that opens with no session records nothing anyway)."""
+    ann = _profiler_annotation()
+    if not ann or not ann.is_enabled():
+        return None
+    a = ann(name, **attrs)
+    a.__enter__()
+    return a
+
+
+def _on_jax_duration(event: str, duration_s: float, **_kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    tel = _GLOBAL
+    if not tel.enabled:
+        return
+    tel.inc(JIT_COMPILES, help="XLA backend compiles; a rise after warm-up "
+                               "is a shape that escaped bucketing")
+    for tr in tel.current_traces():
+        tr.add_completed("jit.compile", duration_s)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """gc.callbacks hook: time each collection on the collecting thread.
+    Never imports (a collection may run inside an import) and never
+    records into a request tree (see the module docstring)."""
+    if phase == "start":
+        if not _GLOBAL.enabled:
+            return
+        _gc_tls.ann = (None if _annotation_cls is None else _annotate(
+            "gc.pause", {"generation": info.get("generation")}))
+        _gc_tls.t0 = time.perf_counter()
+        return
+    t0 = getattr(_gc_tls, "t0", None)
+    if t0 is None:                  # enabled mid-collection
+        return
+    dt = time.perf_counter() - t0
+    _gc_tls.t0 = None
+    if _gc_tls.ann is not None:
+        _gc_tls.ann.__exit__(None, None, None)
+        _gc_tls.ann = None
+    hist = _GLOBAL._metrics.get(GC_PAUSE)     # a dict read: no lock taken
+    if isinstance(hist, DeferredHistogram):
+        hist.defer(dt)
+
+
+def _register_gc_pauses(tel: Telemetry) -> None:
+    """The gc hook feeds only the process-wide registry, so only a
+    registry that becomes it exports the pause histogram."""
+    if tel.enabled:
+        with tel._mlock:
+            tel._metrics.setdefault(GC_PAUSE, DeferredHistogram(
+                GC_PAUSE, "garbage-collection pause (seconds)", GC_BUCKETS))
+
+
 # -- the process-wide registry ----------------------------------------------
 _GLOBAL = Telemetry()
 
@@ -452,5 +605,10 @@ def set_telemetry(telemetry: Telemetry) -> Telemetry:
     """Swap the process-wide registry (tests, the overhead bench's
     disabled baseline).  Returns the new registry."""
     global _GLOBAL
+    _register_gc_pauses(telemetry)
     _GLOBAL = telemetry
     return telemetry
+
+
+_register_gc_pauses(_GLOBAL)
+gc.callbacks.append(_on_gc)

@@ -277,6 +277,13 @@ class MemoryFrontend:
 
     def _send_json(self, handler, code: int, obj: dict,
                    retry_after_s: Optional[float] = None) -> None:
+        with get_telemetry().span("frontend.respond"):
+            self._write_json(handler, code, obj, retry_after_s)
+
+    @staticmethod
+    def _write_json(handler, code: int, obj: dict,
+                    retry_after_s: Optional[float] = None) -> None:
+        """Serialise `obj` and write it as the whole response."""
         rid = getattr(handler, "memori_request_id", None)
         if rid is not None:
             obj.setdefault("request_id", rid)
@@ -431,20 +438,22 @@ class MemoryFrontend:
 
     def _respond_envelope(self, handler, resp: MemoryResponse,
                           extra: Optional[dict] = None) -> None:
-        body = response_to_json(resp)
-        if extra:
-            body.update(extra)
-        if resp.ok:
-            self._send_json(handler, 200, body)
-        elif isinstance(resp.exception, (BackpressureError, AdmissionError)):
-            # capacity, not failure: same backoff contract as admission
-            self._count("rejected")
-            retry = getattr(resp.exception, "retry_after_s", 1.0)
-            body["retry_after_s"] = retry
-            self._send_json(handler, 429, body, retry_after_s=retry)
-        else:
-            self._count("errors")
-            self._send_json(handler, 500, body)
+        with get_telemetry().span("frontend.respond"):
+            body = response_to_json(resp)
+            if extra:
+                body.update(extra)
+            if resp.ok:
+                self._write_json(handler, 200, body)
+            elif isinstance(resp.exception,
+                            (BackpressureError, AdmissionError)):
+                # capacity, not failure: same backoff contract as admission
+                self._count("rejected")
+                retry = getattr(resp.exception, "retry_after_s", 1.0)
+                body["retry_after_s"] = retry
+                self._write_json(handler, 429, body, retry_after_s=retry)
+            else:
+                self._count("errors")
+                self._write_json(handler, 500, body)
 
     # -- endpoints ----------------------------------------------------------
     def _handle_retrieve(self, handler, tenant: str) -> None:

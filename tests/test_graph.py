@@ -66,11 +66,10 @@ def _expand_both(store, queries, namespaces, hops_b, k=16, max_hops=2,
     _, sparse_ids = store.bm25.topk_batch_dev(list(queries), k=8,
                                               namespaces=list(q_ns))
     rankings = [np.asarray(dense_ids), np.asarray(sparse_ids)]
-    ids, scores, _, _ = g.expand(rankings, q_ns,
-                                 store.row_namespaces_device(), tw,
-                                 np.asarray(hops_b, np.int32), k=k,
-                                 max_hops=max_hops, seed_k=seed_k,
-                                 decay=decay)
+    ids, scores = g.expand(rankings, q_ns,
+                           store.row_namespaces_device(), tw,
+                           np.asarray(hops_b, np.int32), k=k,
+                           max_hops=max_hops, seed_k=seed_k, decay=decay)
     row_labels = np.asarray(store.row_namespaces_device())
     es, ed, et, ew = g.edges()
     rs, ro = g.row_incidence()
@@ -299,8 +298,8 @@ def test_no_recompile_no_upload_while_edges_grow_within_bucket(monkeypatch):
     hops_b = np.asarray([2, 2], np.int32)
 
     def run():
-        ids, _, _, _ = g.expand(rankings, q_ns, row_labels, tw, hops_b,
-                                k=16, max_hops=2, seed_k=8, decay=0.5)
+        ids, _ = g.expand(rankings, q_ns, row_labels, tw, hops_b,
+                          k=16, max_hops=2, seed_k=8, decay=0.5)
         return np.asarray(ids)
 
     run()                                 # materialize + compile
@@ -510,9 +509,8 @@ def test_graph_plan_validation():
 # -- telemetry ----------------------------------------------------------------
 
 def test_graph_span_and_metrics_in_scrape():
-    """plan.graph span attrs (frontier sizes, edges touched, launches) in
-    the trace tree, memori_graph_* gauges + the expansion latency histogram
-    in the Prometheus scrape — strict exposition-format checks."""
+    """plan.graph span attrs (launches, compiled hops, graph size) in the
+    trace tree and the memori_graph_* gauges in the Prometheus scrape."""
     from repro.obs.telemetry import Telemetry, set_telemetry, walk_spans
     from repro.serving.frontend import flatten_metrics
     tel = Telemetry()
@@ -527,8 +525,7 @@ def test_graph_span_and_metrics_in_scrape():
         spans = {s["name"]: s for s in walk_spans(tr.to_dict()["root"])}
         g = spans["plan.graph"]["attrs"]
         assert g["launches"] == 1
-        assert len(g["frontier_sizes"]) == g["hops_compiled"]
-        assert len(g["edges_touched"]) == g["hops_compiled"]
+        assert g["hops_compiled"] == 2
         assert g["edges"] == svc.store.graph.n_edges
         # gauges ride the stats() flattening used by /v1/metrics
         names = {n for n, _ in flatten_metrics(svc.stats())}
@@ -536,13 +533,5 @@ def test_graph_span_and_metrics_in_scrape():
                      "memori_graph_edges_causal",
                      "memori_graph_rows_with_incidence"):
             assert want in names, f"missing gauge {want}"
-        # histogram + counters in the exposition text
-        text = tel.render()
-        assert "# TYPE memori_graph_expand_latency_seconds histogram" in text
-        assert "memori_graph_expand_latency_seconds_bucket" in text
-        count = [ln for ln in text.splitlines()
-                 if ln.startswith("memori_graph_expand_latency_seconds_count")]
-        assert count and float(count[0].split()[-1]) >= 1
-        assert "memori_graph_expansions_total" in text
     finally:
         set_telemetry(Telemetry())

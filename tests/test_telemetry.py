@@ -3,10 +3,15 @@ counter exposition, lock-correct concurrent recording checked against a
 numpy oracle, scrape-while-recording consistency, per-request span trees
 propagated frontend -> scheduler -> plan stages, bounded ring buffers for
 traces and structured events (FIFO eviction), slow-query events, the JSONL
-event sink, and the disabled-mode no-op guarantees the overhead bench's
-baseline relies on."""
+event sink, the disabled-mode no-op guarantees the overhead bench's
+baseline relies on, and the mirror of every span into a profiler trace
+(plus the gc and compile hooks) read back from a CPU profile."""
+import gc
+import glob
 import json
 import threading
+import time
+import urllib.request
 
 import numpy as np
 import pytest
@@ -15,9 +20,11 @@ from repro.core import MemoryScheduler, MemoryService
 from repro.core.embedder import HashEmbedder
 from repro.core.api import RetrieveRequest
 from repro.core.extraction import Message
-from repro.obs.telemetry import (DEFAULT_BUCKETS, Counter, Histogram,
-                                 Telemetry, get_telemetry, new_request_id,
+from repro.obs.telemetry import (DEFAULT_BUCKETS, GC_PAUSE, JIT_COMPILES,
+                                 Counter, Histogram, Telemetry,
+                                 get_telemetry, new_request_id,
                                  set_telemetry, span_names, walk_spans)
+from repro.serving.frontend import MemoryFrontend
 
 
 @pytest.fixture()
@@ -271,3 +278,183 @@ def test_registry_reuses_metric_instances():
     assert h1 is h2
     c1 = tel.counter("memori_same_things")
     assert tel.counter("memori_same_things") is c1
+
+
+# -- the profiler mirror --------------------------------------------------------
+
+def _host_lines(log_dir, fn):
+    """Run `fn` under a profiler session; the event names of each host
+    thread line, in the plane's line order."""
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    prof = ProfileData.from_file(path)
+    return [[e.name for e in line.events] for plane in prof.planes
+            if plane.name.startswith("/host:") for line in plane.lines]
+
+
+def _post(fe, path, body):
+    req = urllib.request.Request(
+        fe.address + path, data=json.dumps(body).encode(),
+        headers={"Authorization": "Bearer key-acme"}, method="POST")
+    with urllib.request.urlopen(req, timeout=60) as r:
+        return json.loads(r.read().decode())
+
+
+def test_served_tick_spans_reach_the_profile(tel, tmp_path):
+    """Retrieves over HTTP: the tick, the wait between two ticks, the plan
+    stages, the device wait and the hand-back on one host line (the
+    scheduler's), the response written on another (the handler's)."""
+    svc = MemoryService(HashEmbedder(), use_kernel=False, budget=800)
+    sched = MemoryScheduler(svc, tick_interval_s=0.002, max_batch=16)
+    fe = MemoryFrontend(svc, {"key-acme": "acme"}).start()
+    query = {"namespace": "c0", "query": "Which city?"}
+    try:
+        _post(fe, "/v1/record", {"namespace": "c0", "session_id": "s0",
+                                 "messages": [{"speaker": "U",
+                                               "text": "I live in Madrid.",
+                                               "timestamp": 1.0}]})
+        _post(fe, "/v1/retrieve", query)            # compiles outside
+        # two ticks: an annotation records only if it opens and closes
+        # inside the session, and the first wait opened before it
+        lines = _host_lines(tmp_path, lambda: [
+            _post(fe, "/v1/retrieve", query) for _ in range(2)])
+    finally:
+        fe.close()
+        sched.close()
+    [tick_line] = [ln for ln in lines if "scheduler.tick" in ln]
+    for want in ("scheduler.wait", "plan.embed", "plan.dense", "plan.fuse",
+                 "plan.budget", "device.wait", "scheduler.resolve"):
+        assert want in tick_line, f"{want} missing from {set(tick_line)}"
+    handler = [ln for ln in lines if "frontend.respond" in ln]
+    assert handler and all("frontend" in ln for ln in handler)
+    assert tick_line not in handler
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_annotates_without_an_active_trace(tmp_path, enabled):
+    """A span with no request trace still lands in the profile; a
+    disabled registry emits nothing there, as everywhere else."""
+    t = Telemetry(enabled=enabled, slow_query_s=None)
+
+    def work():
+        with t.span("lonely.span", batch=3):
+            pass
+    names = {n for ln in _host_lines(tmp_path, work) for n in ln}
+    assert ("lonely.span" in names) == enabled
+    assert t.recent_traces() == []
+
+
+def test_gc_pause_is_annotated_and_observed(tel, tmp_path):
+    with tel.span("first.span"):   # loads the annotation class; the gc
+        pass                       # hook itself never imports
+    hist = tel.histogram(GC_PAUSE)
+    was = gc.isenabled()
+    gc.disable()              # only the collection below runs
+    try:
+        before = hist.count
+        names = {n for ln in _host_lines(tmp_path, gc.collect) for n in ln}
+        assert hist.count == before + 1
+    finally:
+        if was:
+            gc.enable()
+    assert "gc.pause" in names
+    assert f"# TYPE {GC_PAUSE} histogram" in tel.render()
+
+
+def test_gc_pause_never_waits_on_the_histogram_lock(tel):
+    """A collection that starts while its own thread holds the pause
+    histogram's lock (a scrape allocating inside `snapshot()`) returns,
+    and its pause is counted at the next read."""
+    hist = tel.histogram(GC_PAUSE)
+    was = gc.isenabled()
+    gc.disable()              # only the collection below runs
+    try:
+        before = hist.count
+        done = threading.Event()
+
+        def collect_under_lock():
+            with hist._lock:
+                gc.collect()
+            done.set()
+        threading.Thread(target=collect_under_lock, daemon=True).start()
+        assert done.wait(30), "the gc hook blocked on the histogram lock"
+        assert hist.count == before + 1
+    finally:
+        if was:
+            gc.enable()
+
+
+def test_only_the_global_registry_exports_gc_pauses(tel):
+    """The hook feeds the process-wide registry alone, so a registry that
+    never became it exports no (forever empty) pause histogram."""
+    assert f"# TYPE {GC_PAUSE} histogram" in tel.render()
+    other = Telemetry(slow_query_s=None)
+    assert GC_PAUSE not in other.render()
+    other.close()
+
+
+def test_jit_compiles_are_counted_and_land_in_active_trees(tel):
+    import jax
+    import jax.numpy as jnp
+    with tel.span("first.span"):          # registers the compile listener
+        pass
+    scale = float(time.time_ns() % 1_000_003)     # a program never seen
+    f = jax.jit(lambda x: x * scale + 1.0)
+    tr = tel.start_trace("compiles", op="retrieve")
+    with tel.activate([tr]):
+        f(jnp.ones(5)).block_until_ready()
+    compiled = tel.counter(JIT_COMPILES).value
+    assert compiled >= 1
+    f(jnp.ones(5)).block_until_ready()           # warm: no compile
+    assert tel.counter(JIT_COMPILES).value == compiled
+    tel.finish_trace(tr)
+    spans = [s for s in walk_spans(tel.get_trace("compiles")["root"])
+             if s["name"] == "jit.compile"]
+    assert spans and all(s["duration_s"] > 0 for s in spans)
+    assert all(s["start_s"] >= 0 for s in spans)    # back-dated inside
+
+
+def test_request_tree_serialises_as_before(tel):
+    """Per-request trees keep their form: the same keys at every level,
+    JSON-clean, and only spans opened under the request's own trace
+    (the scheduler's wait and hand-back, the response write and gc
+    pauses stay out)."""
+    svc = MemoryService(HashEmbedder(), use_kernel=False, budget=800)
+    sched = MemoryScheduler(svc, tick_interval_s=0.002, max_batch=16)
+    try:
+        svc.record("acme/c0", "s0",
+                   [Message("U", "I live in Madrid.", 1.0)])
+        tr = tel.start_trace("form-1", op="retrieve")
+        fut = sched.submit_many(
+            [RetrieveRequest(namespace="acme/c0", query="Which city?")],
+            traces=[tr])[0]
+        assert fut.result(timeout=30).status == "ok"
+        gc.collect()
+        tel.finish_trace(tr)
+    finally:
+        sched.close()
+    d = tel.get_trace("form-1")
+    assert json.loads(json.dumps(d)) == d
+    assert set(d) == {"request_id", "op", "started_unix", "duration_s",
+                      "root"}
+    for sp in walk_spans(d["root"]):
+        assert set(sp) <= {"name", "start_s", "duration_s", "attrs",
+                           "children"}
+        assert {"name", "start_s", "duration_s"} <= set(sp)
+    names = span_names(d)
+    assert names[:3] == ["retrieve", "queued", "scheduler.tick"]
+    assert not {"scheduler.wait", "scheduler.resolve", "frontend.respond",
+                "gc.pause"} & set(names)
+    tick = next(s for s in walk_spans(d["root"])
+                if s["name"] == "scheduler.tick")
+    assert [c["name"] for c in tick["children"]] == [
+        "plan.embed", "plan.dense", "plan.sparse", "plan.fuse",
+        "device.wait", "plan.budget"]
