@@ -466,7 +466,8 @@ def phase_serve(quantize: str, *, bulk_rows: int = BULK_ROWS,
         k = pool_k if quantize == "none" else min(
             vindex.capacity, next_pow2(pool_k * vindex.rescore))
         txt = fn.lower(*args, vindex._labels_dev, q, q_ns[:BATCH],
-                       np.int32(vindex.n), k=k, use_kernel=vindex.use_kernel,
+                       np.int32(vindex.n), np.int32(0), k=k,
+                       use_kernel=vindex.use_kernel,
                        interpret=kops._interpret_default(),
                        uniform=False).as_text()
         check("tpu_custom_call" in txt, "dense stage lowered without the "

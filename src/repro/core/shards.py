@@ -323,11 +323,12 @@ class ShardedBank:
             s, i = self._mesh_fn(kk)(self._bank_dev, self._labels_dev,
                                      queries, q_ns)
         else:
-            s, i = _search_device(self._bank_dev, self._labels_dev, queries,
-                                  q_ns, jnp.int32(self.n_slots), k=kk,
-                                  use_kernel=self.use_kernel,
-                                  interpret=kops._interpret_default(),
-                                  uniform=False)
+            s, i, _ = _search_device(self._bank_dev, self._labels_dev,
+                                     queries, q_ns, jnp.int32(self.n_slots),
+                                     jnp.int32(0), k=kk,
+                                     use_kernel=self.use_kernel,
+                                     interpret=kops._interpret_default(),
+                                     uniform=False)
         if kk < k:
             s = jnp.pad(s, ((0, 0), (0, k - kk)), constant_values=-jnp.inf)
             i = jnp.pad(i, ((0, 0), (0, k - kk)), constant_values=-1)

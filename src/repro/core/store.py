@@ -683,6 +683,7 @@ class MemoryStore:
                 "evicted": len(t.evicted),
             } for ns, t in self._tenants.items()
         }
+        vc = self.vindex.counters
         out = {
             "namespaces": len(self._tenants),
             "bank_rows": self.vindex.n,
@@ -697,10 +698,13 @@ class MemoryStore:
                 "hot_rows": self.vindex.n_resident,
                 "warm_rows": self.vindex.n_warm,
                 "rescore_hit_rate": (
-                    self.vindex.counters["rescore_hits"]
-                    / self.vindex.counters["rescore_rows"]
-                    if self.vindex.counters["rescore_rows"] else None),
-                **self.vindex.counters,
+                    vc["rescore_hits"] / vc["rescore_rows"]
+                    if vc["rescore_rows"] else None),
+                # share of the kernel's (query tile, bank block) steps
+                # that held a row some query of the tile could match
+                "scan_share": (vc["blocks_scanned"] / vc["blocks_total"]
+                               if vc["blocks_total"] else None),
+                **vc,
             },
             "per_namespace": per_ns,
             # flatten_metrics exports these as memori_graph_* gauges

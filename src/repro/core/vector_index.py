@@ -163,41 +163,48 @@ def quantize_rows_np(vecs: np.ndarray):
 
 @functools.partial(jax.jit,
                    static_argnames=("k", "use_kernel", "interpret", "uniform"))
-def _search_device(bank, labels, queries, q_ns, n_valid, *, k: int,
+def _search_device(bank, labels, queries, q_ns, n_valid, scanned, *, k: int,
                    use_kernel: bool, interpret: bool, uniform: bool):
     """The stable-shape jitted hot path: one masked top-k over the padded
     device bank.  `n_valid` is traced — appends within a capacity bucket
     reuse this executable.  With `uniform=True` the namespace structure is
     collapsed (any live row matches: the single-tenant / tombstone-only
-    search).  Empty slots come back as (-inf, -1)."""
+    search).  Empty slots come back as (-inf, -1).  `scanned` is the
+    device-side running count of the bank blocks the kernel scanned; it
+    comes back with this launch's blocks added."""
     bank_ns = jnp.where(labels >= 0, 0, -1) if uniform else labels
     if use_kernel:
-        s, i = _tm.topk_mips(queries, bank, k, n_valid=n_valid, q_ns=q_ns,
-                             bank_ns=bank_ns, interpret=interpret)
+        s, i, n = _tm.topk_mips_counted(queries, bank, k, n_valid=n_valid,
+                                        q_ns=q_ns, bank_ns=bank_ns,
+                                        interpret=interpret)
+        scanned = scanned + n
     else:
         s, i = kref.topk_mips_masked_ref(queries, bank, q_ns, bank_ns, k=k,
                                          n_valid=n_valid)
-    return jnp.where(i >= 0, s, -jnp.inf), i
+    return jnp.where(i >= 0, s, -jnp.inf), i, scanned
 
 
 @functools.partial(jax.jit,
                    static_argnames=("k", "use_kernel", "interpret", "uniform"))
-def _search_device_quant(bank_i8, scales, labels, queries, q_ns, n_valid, *,
-                         k: int, use_kernel: bool, interpret: bool,
-                         uniform: bool):
+def _search_device_quant(bank_i8, scales, labels, queries, q_ns, n_valid,
+                         scanned, *, k: int, use_kernel: bool,
+                         interpret: bool, uniform: bool):
     """Quantized twin of `_search_device`: one fused dequant+MIPS launch
     over the int8 code bank (the bank scan reads 1 byte/element).  Same
-    traced-`n_valid` stable-shape contract; empty slots are (-inf, -1)."""
+    traced-`n_valid` stable-shape contract and `scanned` count; empty
+    slots are (-inf, -1)."""
     bank_ns = jnp.where(labels >= 0, 0, -1) if uniform else labels
     if use_kernel:
-        s, i = _tm.topk_mips(queries, bank_i8, k, n_valid=n_valid, q_ns=q_ns,
-                             bank_ns=bank_ns, scales=scales,
-                             interpret=interpret)
+        s, i, n = _tm.topk_mips_counted(queries, bank_i8, k,
+                                        n_valid=n_valid, q_ns=q_ns,
+                                        bank_ns=bank_ns, scales=scales,
+                                        interpret=interpret)
+        scanned = scanned + n
     else:
         s, i = kref.topk_mips_quant_masked_ref(queries, bank_i8, scales,
                                                q_ns, bank_ns, k=k,
                                                n_valid=n_valid)
-    return jnp.where(i >= 0, s, -jnp.inf), i
+    return jnp.where(i >= 0, s, -jnp.inf), i, scanned
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -216,6 +223,9 @@ def _rescore_exact(queries, cand_rows, cand_ids, *, k: int):
     top_i = jnp.take_along_axis(cand_ids, pos, axis=1)
     top_i = jnp.where(top_s > _tm.NEG_INF / 2, top_i, -1)
     return jnp.where(top_i >= 0, top_s, -jnp.inf), top_i
+
+
+_I32_MAX = 2 ** 31 - 1
 
 
 def _next_capacity(n: int, floor: int = 64) -> int:
@@ -253,9 +263,26 @@ class VectorIndex:
         # quantized-search observability: rescore_hits / rescore_rows is
         # the fraction of final top-k ids the quantized ordering already
         # had in ITS top-k (how often the rescore merely re-scores rather
-        # than re-ranks) — exported as the "rescore hit rate" gauge
-        self.counters = {"quant_searches": 0, "rescore_rows": 0,
-                         "rescore_hits": 0}
+        # than re-ranks) — exported as the "rescore hit rate" gauge.
+        # blocks_total sums the (query tile, bank block) steps of every
+        # kernel launch; the scanned ones are counted on the device
+        self._counters = {"quant_searches": 0, "rescore_rows": 0,
+                          "rescore_hits": 0, "blocks_total": 0}
+        # (host base, device i32 or None): blocks_scanned = base + device.
+        # Searches add to the device scalar without waiting on it; one
+        # tuple swapped whole keeps a concurrent reader consistent.
+        # `_unfolded` is the blocks_total of the launches the device
+        # scalar holds, so it is folded into the base before it can wrap
+        self._scanned = (0, None)
+        self._unfolded = 0
+
+    @property
+    def counters(self) -> dict:
+        """Search counters, with `blocks_scanned` read from the device
+        (one scalar transfer): read them from stats(), not per search."""
+        base, dev = self._scanned
+        return dict(self._counters, blocks_scanned=base + (
+            int(dev) if dev is not None else 0))
 
     # -- device residency ---------------------------------------------------
     @property
@@ -611,17 +638,20 @@ class VectorIndex:
         if labels is None:
             labels = self._labels_dev
         kk = min(k, self.capacity)
+        scanned = self._count_launch(queries.shape[0])
         if self.quantize == "none":
-            s, i = _search_device(
+            s, i, scanned = _search_device(
                 self._bank_dev, labels, queries, q_ns, jnp.int32(self.n),
-                k=kk, use_kernel=self.use_kernel,
+                scanned, k=kk, use_kernel=self.use_kernel,
                 interpret=kops._interpret_default(), uniform=uniform)
+            self._scanned = (self._scanned[0], scanned)
             return s, i, kk
         kc = min(self.capacity, _next_pow2(kk * self.rescore))
-        s, i = _search_device_quant(
+        s, i, scanned = _search_device_quant(
             self._bank_dev, self._scales_dev, labels, queries, q_ns,
-            jnp.int32(self.n), k=kc, use_kernel=self.use_kernel,
+            jnp.int32(self.n), scanned, k=kc, use_kernel=self.use_kernel,
             interpret=kops._interpret_default(), uniform=uniform)
+        self._scanned = (self._scanned[0], scanned)
         tel = get_telemetry()
         # the rescore's host work; its two device reads are timed apart
         with tel.span("dense.rescore"):
@@ -630,16 +660,31 @@ class VectorIndex:
             cand = self._bank[np.clip(i_host, 0, self.capacity - 1)]
             s, i = _rescore_exact(queries, jnp.asarray(cand),
                                   jnp.asarray(i_host), k=kk)
-            self.counters["quant_searches"] += 1
+            self._counters["quant_searches"] += 1
             with tel.span("device.wait"):
                 i_np = np.asarray(i)                 # small (Q, k) D2H
             firstk = i_host[:, :kk]
             for r in range(i_np.shape[0]):
                 fin = i_np[r][i_np[r] >= 0]
-                self.counters["rescore_rows"] += int(fin.size)
-                self.counters["rescore_hits"] += int(np.isin(
+                self._counters["rescore_rows"] += int(fin.size)
+                self._counters["rescore_hits"] += int(np.isin(
                     fin, firstk[r]).sum())
         return s, i, kk
+
+    def _count_launch(self, Q: int):
+        """Count one launch's blocks on the host; return the device
+        scalar its scanned blocks are added to.  The device scalar is
+        read into the host base only once per 2^31 counted blocks."""
+        total = _tm.grid_blocks(Q, self.capacity) if self.use_kernel else 0
+        base, dev = self._scanned
+        if dev is None or self._unfolded + total > _I32_MAX:
+            self._scanned = (base + (int(dev) if dev is not None else 0),
+                             None)
+            self._unfolded = 0
+            dev = jnp.zeros((), jnp.int32)
+        self._counters["blocks_total"] += total
+        self._unfolded += total
+        return dev
 
     def _to_host(self, s, i, k: int, kk: int):
         s = np.asarray(s)

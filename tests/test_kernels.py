@@ -165,6 +165,98 @@ def test_topk_scores_sorted_and_indices_valid():
     assert ((np.asarray(i) >= 0) & (np.asarray(i) < 77)).all()
 
 
+# 8 bank blocks of 32 rows, 2 query tiles of 8: (bank_ns, q_ns, n_valid)
+_SKIP_N, _SKIP_BN, _SKIP_Q, _SKIP_BQ = 256, 32, 16, 8
+
+
+def _skip_layout(case):
+    r = np.arange(_SKIP_N)
+    nv = _SKIP_N
+    if case == "clustered_on_boundaries":      # one tenant per block
+        bank_ns = r // 32
+        q_ns = [0, 0, 2, 2, 0, 2, 0, 2, 5, 5, 6, 5, 6, 6, 5, 5]
+    elif case == "clustered_across_boundaries":     # runs of 48 rows
+        bank_ns = r // 48
+        q_ns = [1, 3, 1, 3, 1, 1, 3, 3, 4, 4, 4, 2, 2, 4, 2, 4]
+    elif case == "interleaved":               # every block flagged
+        bank_ns = r % 5
+        q_ns = list(np.arange(_SKIP_Q) % 5)
+    elif case == "tombstone_block":
+        bank_ns = r // 64
+        bank_ns[64:96] = -1                   # block 2 all dead
+        q_ns = [1, 1, 0, 1, 1, 1, 0, 1, 3, 1, 3, 3, 1, 3, 3, 1]
+    elif case == "small_tenant_spread":       # 3 rows < k in blocks 0, 3, 7
+        bank_ns = r // 32
+        bank_ns[[3, 100, 230]] = 9
+        q_ns = [9, 9, 4, 9, 9, 9, 4, 9, 9, 1, 9, 1, 9, 9, 1, 9]
+    elif case == "n_valid_mid_block":
+        bank_ns = r // 32
+        nv = 150                              # block 4 holds rows 128..149
+        q_ns = [4, 4, 0, 4, 0, 4, 4, 0, 6, 5, 6, 6, 5, 5, 6, 4]
+    elif case == "empty_namespace":           # ns 42 owns no row
+        bank_ns = r // 32
+        q_ns = [42] * 8 + [42, 1, 42, 42, 1, 42, 42, 42]
+    elif case == "tiles_differ":              # Q > block_q, disjoint sets
+        bank_ns = r // 32
+        q_ns = [0] * 8 + [7] * 8
+    else:
+        raise ValueError(case)
+    return (np.asarray(bank_ns, np.int32), np.asarray(q_ns, np.int32), nv)
+
+
+def _flagged_blocks(bank_ns, q_ns, nv):
+    """Blocks holding a live row below nv that some query of the tile
+    asks for, summed over tiles (worked out without the kernel)."""
+    live = np.where(np.arange(_SKIP_N) < nv, bank_ns, -2)
+    n = 0
+    for t in range(_SKIP_Q // _SKIP_BQ):
+        want = set(q_ns[t * _SKIP_BQ:(t + 1) * _SKIP_BQ].tolist())
+        for b in range(_SKIP_N // _SKIP_BN):
+            blk = live[b * _SKIP_BN:(b + 1) * _SKIP_BN]
+            n += any(x >= 0 and x in want for x in blk.tolist())
+    return n
+
+
+@pytest.mark.parametrize("case", [
+    "clustered_on_boundaries", "clustered_across_boundaries", "interleaved",
+    "tombstone_block", "small_tenant_spread", "n_valid_mid_block",
+    "empty_namespace", "tiles_differ"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+def test_topk_mips_masked_block_skip_matches_oracle(case, dtype):
+    """Masked launches scan only the blocks some query of a tile can match
+    and still answer exactly like the oracle: ids equal, scores close, and
+    the scanned count is the hand-worked count of flagged blocks."""
+    from repro.kernels import topk_mips as tm
+    bank_ns, q_ns, nv = _skip_layout(case)
+    kk, D = 8, 16
+    q = jax.random.normal(k(61), (_SKIP_Q, D))
+    bank = jax.random.normal(k(62), (_SKIP_N, D))
+    scales = None
+    if dtype == "int8":
+        bank, scales = ref.quantize_rows_ref(bank)
+        sr, ir = ref.topk_mips_quant_masked_ref(q, bank, scales, q_ns,
+                                                bank_ns, k=kk, n_valid=nv)
+    else:
+        q, bank = q.astype(dtype), bank.astype(dtype)
+        sr, ir = ref.topk_mips_masked_ref(q, bank, q_ns, bank_ns, k=kk,
+                                          n_valid=nv)
+    search = jax.jit(lambda *a: tm.topk_mips_counted(
+        *a[:2], kk, n_valid=a[2], q_ns=a[3], bank_ns=a[4], scales=a[5],
+        block_q=_SKIP_BQ, block_n=_SKIP_BN, interpret=True))
+    s, i, scanned = search(q, bank, jnp.int32(nv), jnp.asarray(q_ns),
+                           jnp.asarray(bank_ns), scales)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ir))
+    live = np.asarray(ir) >= 0
+    np.testing.assert_allclose(np.asarray(s)[live], np.asarray(sr)[live],
+                               rtol=1e-4, atol=1e-4)
+    assert (np.asarray(s)[~live] == ref.NEG_INF).all()
+    want = _flagged_blocks(bank_ns, q_ns, nv)
+    assert int(scanned) == want
+    total = tm.grid_blocks(_SKIP_Q, _SKIP_N, _SKIP_BQ, _SKIP_BN)
+    assert total == 16
+    assert want == total if case == "interleaved" else want < total
+
+
 # ---------------------------------------------------------------------------
 # topk_mips — quantized (int8 bank + per-row scales, fused dequant)
 # ---------------------------------------------------------------------------

@@ -13,6 +13,7 @@ from repro.core import vector_index as vi_mod
 from repro.core.embedder import HashEmbedder
 from repro.core.extraction import Message
 from repro.core.service import MemoryService
+from repro.core.store import MemoryStore
 from repro.core.tiering import TierManager, TierPolicy
 from repro.core.vector_index import VectorIndex, quantize_rows_np
 from repro.kernels import ref as kref
@@ -233,6 +234,70 @@ def test_no_recompile_no_bank_upload_steady_state(quantize, monkeypatch):
         np.asarray(i)
     assert cc.count == 0, f"recompiled {cc.count}x: {cc.msgs[:3]}"
     assert uploads == [], f"bank-sized host->device transfers: {uploads}"
+
+
+def _device_reads(monkeypatch):
+    """The device arrays the host reads: np.asarray of a jax.Array (on
+    the CPU numpy takes its buffer directly) and int()/float() (through
+    `ArrayImpl._value`), each array counted once."""
+    import jax
+    from jax._src.array import ArrayImpl
+    reads = {}
+    real_value, real_asarray = ArrayImpl._value, np.asarray
+
+    def spy_value(self):
+        reads.setdefault(id(self), self.shape)
+        return real_value.fget(self)
+
+    def spy_asarray(x, *a, **kw):
+        if isinstance(x, jax.Array):
+            reads.setdefault(id(x), x.shape)
+        return real_asarray(x, *a, **kw)
+
+    monkeypatch.setattr(ArrayImpl, "_value", property(spy_value))
+    monkeypatch.setattr(np, "asarray", spy_asarray)
+    return reads
+
+
+@pytest.mark.parametrize("quantize", ["none", "int8"])
+def test_block_scan_counters_and_no_added_host_sync(quantize, monkeypatch):
+    """The kernel's scan counters on a tenant-clustered bank of 4 blocks
+    of 512 rows: ns 0 fills block 0, ns 1 block 1, ns 2 the head of block
+    2, and block 3 lies beyond n.  Each launch adds its tile x block steps
+    to blocks_total and its flagged steps to blocks_scanned; stats()
+    exports scan_share.  The count stays on the device: a search_batch
+    reads from the device no more than it did without it (nothing in f32,
+    the rescore's two reads in int8), and reading the counters does."""
+    store = MemoryStore(HashEmbedder(dim=8), dim=8, quantize=quantize,
+                        rescore=2)
+    vi = store.vindex
+    vi.add(RNG.standard_normal((1100, 8)).astype(np.float32),
+           ns=[0] * 512 + [1] * 512 + [2] * 76)
+    assert vi.capacity == 2048
+    assert store.stats()["bank"]["scan_share"] is None
+    q = RNG.standard_normal((2, 8)).astype(np.float32)
+    vi.search_batch(q, [0, 2], k=4)            # one tile: blocks 0 and 2
+    vi.search_batch(q[:1], [1], k=4)           # block 1
+    bank = store.stats()["bank"]
+    assert (bank["blocks_scanned"], bank["blocks_total"]) == (3, 8)
+    assert bank["scan_share"] == 3 / 8
+    vi.search(q, k=4)          # uniform: every block with a live row < n
+    assert (vi.counters["blocks_scanned"], vi.counters["blocks_total"]) \
+        == (6, 12)
+
+    reads = _device_reads(monkeypatch)
+    vi.search_batch(q, [0, 2], k=4)
+    assert len(reads) == (0 if quantize == "none" else 2), reads
+    assert vi.counters["blocks_scanned"] == 8
+    assert len(reads) == (1 if quantize == "none" else 3), reads
+
+
+@pytest.mark.parametrize("quantize", ["none", "int8"])
+def test_uniform_search_over_full_bank_scans_every_block(quantize):
+    vi = VectorIndex(dim=8, capacity=1024, quantize=quantize, rescore=2)
+    vi.add(RNG.standard_normal((1024, 8)).astype(np.float32))
+    vi.search(RNG.standard_normal((3, 8)).astype(np.float32), k=4)
+    assert vi.counters["blocks_scanned"] == vi.counters["blocks_total"] == 2
 
 
 @pytest.mark.parametrize("quantize", ["none", "int8"])
