@@ -2,12 +2,14 @@
 
 Set-up: build the server exactly as `python -m repro.launch.serve` builds
 it (`build_server`: agent engine, MemoryService, MemoryScheduler, HTTP
-frontend on localhost), fill the bank through the program's write path,
-and warm the dense/fuse programs at every power-of-two batch bucket the
-scheduler can form.  Window: the open-loop load generator (bench/loadgen.py,
-a child process without JAX) sends the seeded schedule over HTTP.  After
-the window: device memory is read, the server is closed, and every answer
-is compared with the plain reference.
+frontend on localhost, plus the configuration's `serve_args`), fill the
+bank through the program's write path, and let the cell's mix
+(bench/mixes/<kind>.py) warm every program its traffic uses.  Window: the
+open-loop load generator (bench/loadgen.py, a child process without JAX)
+sends the mix's seeded schedule over HTTP.  After the window: device
+memory is read, the mix reads back what it wrote through the served API,
+the server is closed, and the mix compares every answer with its plain
+reference.
 """
 from __future__ import annotations
 
@@ -26,8 +28,8 @@ import numpy as np
 import data
 import reference
 import devtrace as trace_mod
-from spec import BENCH_DIR, ROOT, Cell, load_peaks, load_reader
-from traffic import API_KEY, TENANT, schedule
+from spec import BENCH_DIR, ROOT, Cell, load_mix, load_peaks, load_reader
+from traffic import API_KEY, RETRIEVE, TENANT, check_items
 
 sys.path.insert(0, str(ROOT / "src"))
 
@@ -71,6 +73,7 @@ def build(config: dict, seed: int):
             "--api-keys", f"{API_KEY}={TENANT}"]
     if config.get("host_demo"):
         argv.append("--host-demo")
+    argv += config.get("serve_args", [])
     server = serve.build_server(serve.parse_args(argv))
     svc = server.service
     # the program has no flag for these: hold it to what the
@@ -92,23 +95,6 @@ def fill(server, config: dict, seed: int, vectors: np.ndarray) -> None:
         raise RuntimeError(f"the bank holds {svc.vindex.n} rows, the "
                            f"configuration states {vectors.shape[0]}")
     log(f"[fill] rows={svc.vindex.n} capacity={svc.vindex.capacity}")
-
-
-def warm(server, config: dict, traffic: dict) -> None:
-    """Every batch bucket the scheduler can form, for the mix's stages."""
-    from repro.core.api import RetrieveRequest
-    svc = server.service
-    body = traffic["retrieve"]
-    b = 1
-    while b <= config["max_batch"]:
-        for n in sorted({b, b // 2 + 1}):
-            svc.execute([RetrieveRequest(
-                namespace=f"{TENANT}/bulk{i % config['tenants']}",
-                query=f"What does {data.NAMES[i % len(data.NAMES)]} like?",
-                top_k=body.get("top_k"),
-                stages=tuple(body["stages"]) if "stages" in body else None)
-                for i in range(n)])
-        b *= 2
 
 
 def _spans(traces: List[dict], t0: float, t1: float) -> List[dict]:
@@ -137,8 +123,9 @@ def _spans(traces: List[dict], t0: float, t1: float) -> List[dict]:
     return [d for d in out.values() if t0 <= d["start_unix"] <= t1]
 
 
-def setup(config: dict, traffic: dict, seed: int):
-    """The served stack, filled and warm, and the bulk vectors it holds."""
+def setup(config: dict, traffic: dict, seed: int, mix):
+    """The served stack, filled and warmed by `mix`, and the bulk vectors
+    it holds."""
     t = time.time()
     server = build(config, seed)
     server.frontend.start()
@@ -147,7 +134,7 @@ def setup(config: dict, traffic: dict, seed: int):
     t_build = time.time()
     fill(server, config, seed, vectors)
     t_fill = time.time()
-    warm(server, config, traffic)
+    mix.warm(server, config, traffic)
     t_warm = time.time()
     # the fill leaves the collector a debt of millions of young objects; a
     # full collection paid here keeps a multi-second pause out of the
@@ -168,11 +155,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     compiles = CompileLog()
     config, traffic = cell.config, cell.traffic
-    items = schedule(traffic, config, seed, seconds)
+    mix = load_mix(cell.mix)
+    items = mix.schedule(traffic, config, seed, seconds)
+    check_items(items)
+    n_ops = sum(len(it["ops"]) for it in items)
     if trace:
         telemetry.set_telemetry(telemetry.Telemetry(
-            trace_capacity=4 * len(items) + 1024))
-    server, vectors = setup(config, traffic, seed)
+            trace_capacity=4 * n_ops + 1024))
+    server, vectors = setup(config, traffic, seed, mix)
 
     if trace:
         tmp = tempfile.mkdtemp(prefix="bench-trace-")
@@ -191,6 +181,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         raise RuntimeError("load generator failed: "
                            + proc.stderr.decode()[-2000:])
     res = json.loads(proc.stdout)
+    results = res["items"]
     t0, t1 = res["t0_unix"], res["end_unix"]
     if trace:
         jax.profiler.stop_trace()
@@ -200,24 +191,24 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     n_lower = compiles.count(t0, t1, _LOWER)
     n_compile = compiles.count(t0, t1, _COMPILE)
     traces = (telemetry.get_telemetry().recent_traces(
-        limit=4 * len(items) + 1024) if trace else [])
-    live_rows = {t: config["rows_per_tenant"]
-                 for t in range(config["tenants"])}
+        limit=4 * n_ops + 1024) if trace else [])
+    t_after = time.time()
+    after = mix.after_window(server, config, items, results)
+    log(f"[after] after_window_s={time.time() - t_after:.3f}")
     server.frontend.close()
     server.service.close()
     del server
     gc.collect()
 
-    answers = res["retrieves"]
-    numbers = reference.compare(items, answers, vectors,
-                                config["rows_per_tenant"], config["pool"],
-                                reference.Embedder(config["dim"]))
+    numbers = mix.compare(items, results, SimpleNamespace(
+        config=config, seed=seed, vectors=vectors, after=after))
     limits = config["limits"]
     correct = reference.verdict(numbers, limits)
 
-    lat = np.array([a["latency_s"] if a.get("ok") else np.inf
-                    for a in answers], np.float64)
-    late = np.array([a.get("late_s", 0.0) for a in answers])
+    ops = op_results(results)
+    answers = [o for o in ops if o["path"] == RETRIEVE]
+    lat = latencies(answers)
+    late = np.array([r.get("late_s", 0.0) for r in results])
     values = {"setup_s": t0 - t_start,
               "retrieve_p50_ms": _pct(lat, 50) * 1e3,
               "retrieve_p95_ms": _pct(lat, 95) * 1e3,
@@ -225,8 +216,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     device = {"platform": dev[0].platform, "kind": dev[0].device_kind,
               "count": cell.chips, "memory_peak_bytes": int(peak)}
     out = {"correct": correct,
-           "attempted": len(answers),
-           "failed": int(sum(not a.get("ok") for a in answers))}
+           "attempted": len(ops),
+           "failed": int(sum(not o.get("ok") for o in ops))}
     breakdown = None
     if trace:
         spans = _spans(traces, t0, t1)
@@ -235,10 +226,14 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
         dtr = trace_mod.reduce(events, (t0, t1), sync_unix, KERNELS,
                                spans)
         _require_kernels(cell, dtr)
+        # what a reader sees: `answers` are the retrieve ops' results,
+        # `ops` every op's; the window's first op of item i was request
+        # r<i>, a later op j request r<i>.<j>
         obs = SimpleNamespace(
             config=config, traffic=traffic, items=items, answers=answers,
-            spans=spans, device=dtr,
-            live_rows=live_rows, peaks=load_peaks(dev[0].device_kind))
+            ops=ops, spans=spans, device=dtr,
+            live_rows=mix.live_rows(config, items, results),
+            peaks=load_peaks(dev[0].device_kind))
         metrics = {}
         for m in cell.per_layer:
             v = load_reader(m["name"])(obs)
@@ -254,8 +249,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
                                "unit": m["unit"]}
                    for m in cell.end_to_end}
 
-    log(f"[window] requests={len(answers)} "
+    log(f"[window] requests={len(items)} ops={len(ops)} "
         f"seconds={seconds} drained_s={t1 - t0:.3f}")
+    for path in sorted({o["path"] for o in ops} - {RETRIEVE}):
+        # no end-to-end metric yet: the first cell that writes sets the
+        # bound of one from its spreads on the chip
+        x = latencies([o for o in ops if o["path"] == path])
+        name = path.rsplit("/", 1)[-1]
+        log(f"[window] {name}_p50_ms={_pct(x, 50) * 1e3!r} "
+            f"{name}_p95_ms={_pct(x, 95) * 1e3!r} n={x.size}")
     log(f"[window] compiles_in_window={n_compile} "
         f"lowerings_in_window={n_lower}")
     log(f"[window] generator_late_p50_ms={_pct(late, 50) * 1e3:.3f} "
@@ -285,6 +287,17 @@ def _require_kernels(cell: Cell, dtr) -> None:
                 f"no device op in the traced window matches {kernel}'s "
                 f"pattern {pattern!r}; the heaviest ops were "
                 f"{[k for k, _ in dtr.ops] if dtr else []}")
+
+
+def op_results(results: List[dict]) -> List[dict]:
+    """Every op's result of the load generator's items, in item order."""
+    return [o for r in results for o in r["ops"]]
+
+
+def latencies(ops: List[dict]) -> np.ndarray:
+    """Seconds per op; a failed or unsent op is infinitely slow."""
+    return np.array([o["latency_s"] if o.get("ok") else np.inf
+                     for o in ops], np.float64)
 
 
 def _pct(x: np.ndarray, q: float) -> float:

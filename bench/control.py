@@ -1,6 +1,7 @@
 """The control: the reference put in the program's place, computed one
 precision step below what the configuration states, must come out not
-correct.  Not part of a benchmark run.
+correct.  Not part of a benchmark run.  It controls dense ranking, so it
+takes only cells of the `rank` mix.
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3 --seconds 10
 
@@ -20,6 +21,7 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+from types import SimpleNamespace
 from typing import Callable, Dict, List, Sequence
 
 import numpy as np
@@ -27,7 +29,6 @@ import numpy as np
 import data
 import reference
 import spec
-from traffic import schedule
 
 RESCORE = 4                       # the served over-fetch of the int8 path
 
@@ -86,8 +87,9 @@ def control_answers(items: Sequence[dict], vectors: np.ndarray,
     for it in items:
         row0 = it["tenant"] * rows_per_tenant
         rows = vectors[row0: row0 + rows_per_tenant]
-        ids = row0 + rank(rows, embed_f32(it["body"]["query"]))
-        w = float(it["body"].get("dense_weight", 1.0))
+        body = it["ops"][0]["body"]
+        ids = row0 + rank(rows, embed_f32(body["query"]))
+        w = float(body.get("dense_weight", 1.0))
         out.append({"ok": True, "row_ids": ids.tolist(),
                     "scores": reference.rrf_scores(ids.size, w).tolist()})
     return out
@@ -98,16 +100,25 @@ def embed_f32(dim: int) -> Callable:
     return lambda text: e(text).astype(np.float32)
 
 
+def rank_mix(cell):
+    """The `rank` mix module; any other mix is refused by name."""
+    if cell.mix != "rank":
+        raise SystemExit(f"control: {cell.name} runs the {cell.mix!r} mix; "
+                         "the control is of dense ranking, the 'rank' mix")
+    return spec.load_mix("rank")
+
+
 def readings(cell, seed: int, seconds: float, mode: str,
              dot: Callable) -> Dict[str, float]:
+    mix = rank_mix(cell)
     c = cell.config
     vectors = data.bulk_vectors(seed, c["tenants"] * c["rows_per_tenant"],
                                 c["dim"])
-    items = schedule(cell.traffic, c, seed, seconds)
+    items = mix.schedule(cell.traffic, c, seed, seconds)
     ans = control_answers(items, vectors, c["rows_per_tenant"], c["pool"],
                           ranker(mode, c["pool"], dot), embed_f32(c["dim"]))
-    return reference.compare(items, ans, vectors, c["rows_per_tenant"],
-                             c["pool"], reference.Embedder(c["dim"]))
+    return mix.compare(items, [{"ops": [a]} for a in ans], SimpleNamespace(
+        config=c, seed=seed, vectors=vectors, after={}))
 
 
 def main(argv=None) -> int:
@@ -117,6 +128,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=10.0)
     args = ap.parse_args(argv)
     cell = spec.resolve(args.workload)
+    rank_mix(cell)
     import jax
     import jax.numpy as jnp
     if jax.devices()[0].platform != "tpu":

@@ -6,7 +6,8 @@ written into the cell's traffic file by hand.
     python3 bench/knee.py --workload <cell> --seed <n> --seconds 5 \
         --rates 100,200,400,800
 
-Prints per rate: requests, p50/p95 latency, the median latency of the
+Schedules with the cell's mix.  Prints per rate: requests, ops, failed
+ops, p50/p95 latency of the retrieves, the median retrieve latency of the
 window's first and last fifths (a backlog that grows shows as the last
 fifth lagging the first), and the time the queue took to drain after the
 last arrival.
@@ -27,7 +28,7 @@ import numpy as np  # noqa: E402
 import cell as cell_mod  # noqa: E402
 import spec  # noqa: E402
 from run import require_chips  # noqa: E402
-from traffic import API_KEY, schedule  # noqa: E402
+from traffic import API_KEY, RETRIEVE  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -43,13 +44,14 @@ def main(argv=None) -> int:
     jax.config.update("jax_compilation_cache_dir", str(cell_mod.CACHE_DIR))
     compiles = cell_mod.CompileLog()
     config, traffic = cell.config, cell.traffic
-    server, _ = cell_mod.setup(config, traffic, args.seed)
+    mix = spec.load_mix(cell.mix)
+    server, _ = cell_mod.setup(config, traffic, args.seed, mix)
     print(f"[knee] workload={args.workload} setup_s={time.time() - T_START:.1f}",
           flush=True)
     rows = []
     for j, rate in enumerate(float(r) for r in args.rates.split(",")):
-        items = schedule(dict(traffic, rate_per_s=rate), config,
-                         args.seed + j + 1, args.seconds)
+        items = mix.schedule(dict(traffic, rate_per_s=rate), config,
+                             args.seed + j + 1, args.seconds)
         job = {"address": server.frontend.address, "api_key": API_KEY,
                "workers": traffic["workers"], "drain_s": 60.0,
                "items": items}
@@ -57,18 +59,19 @@ def main(argv=None) -> int:
             [sys.executable, str(spec.BENCH_DIR / "loadgen.py")],
             input=json.dumps(job).encode(), capture_output=True, check=True)
         res = json.loads(proc.stdout)
-        ans = res["retrieves"]
-        lat = np.array([a["latency_s"] if a.get("ok") else np.inf
-                        for a in ans])
+        ops = cell_mod.op_results(res["items"])
+        ans = [o for o in ops if o["path"] == RETRIEVE]
+        lat = cell_mod.latencies(ans)
         fifth = max(1, len(lat) // 5)
-        row = {"rate": rate, "requests": len(ans),
-               "failed": int(sum(not a.get("ok") for a in ans)),
+        row = {"rate": rate, "requests": len(items), "ops": len(ops),
+               "failed": int(sum(not o.get("ok") for o in ops)),
                "p50_ms": cell_mod._pct(lat, 50) * 1e3,
                "p95_ms": cell_mod._pct(lat, 95) * 1e3,
                "first_fifth_p50_ms": float(np.median(lat[:fifth])) * 1e3,
                "last_fifth_p50_ms": float(np.median(lat[-fifth:])) * 1e3,
                "drain_s": res["end_unix"] - res["t0_unix"] - args.seconds,
-               "late_max_ms": max(a.get("late_s", 0.0) for a in ans) * 1e3,
+               "late_max_ms": max(r.get("late_s", 0.0)
+                                  for r in res["items"]) * 1e3,
                "mean_batch": float(np.mean([a.get("batch_size") or 0
                                             for a in ans])),
                "compiles": compiles.count(res["t0_unix"], res["end_unix"],

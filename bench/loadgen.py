@@ -4,13 +4,17 @@ JAX (the server process holds the chip).
 Reads one JSON object on stdin:
 
     {"address": "http://127.0.0.1:PORT", "api_key": ..., "workers": N,
-     "drain_s": 60, "items": [schedule entries from bench/traffic.py]}
+     "drain_s": 60, "items": [a mix's schedule (bench/mixes/<kind>.py)]}
 
-sends every item's /v1/retrieve when it falls due, and writes
-one JSON object to stdout: the window's start on the host's unix clock,
-and per request its status, its latency timed from when it was due, how
-late the generator sent it, the server's queued_s/service_s, and the
-answer fields the correctness check reads.
+An item is `{i, due, tenant, ops: [{path, body, keep}]}`.  When an item
+falls due, one worker sends its ops in order on its connection, each
+after the previous reply; an op that fails leaves the item's later ops
+unsent.  Writes one JSON object to stdout: the window's start and end on
+the host's unix clock, and per item how late the generator sent it and
+per op its path, status, `ok`, its latency (the first op's timed from when
+the item was due, a later op's from when it was sent), the server's
+queued_s, service_s and batch_size, and the payload fields its `keep`
+names, which the mix's check reads.
 """
 from __future__ import annotations
 
@@ -23,11 +27,15 @@ import time
 import urllib.parse
 
 
-def _answer(env: dict) -> dict:
-    p = env.get("payload") or {}
-    if p.get("kind") == "raw_retrieval":
-        return {"row_ids": p["row_ids"], "scores": p["scores"]}
-    return {}
+def _kept(env: dict, keep) -> dict:
+    p = env.get("payload")
+    if not isinstance(p, dict):
+        return {}
+    return {k: p[k] for k in keep if k in p}
+
+
+def _unsent(op: dict, why: str) -> dict:
+    return {"path": op["path"], "status": -1, "ok": False, "error": why}
 
 
 class _Client:
@@ -74,21 +82,32 @@ def run(job: dict) -> dict:
             it = work.get()
             if it is None:
                 return
-            due = t0 + it["due"]
-            sent = time.monotonic()
-            res = {"i": it["i"], "late_s": sent - due}
-            try:
-                code, env = cli.post("/v1/retrieve", it["body"],
-                                     f"r{it['i']}")
-                done = time.monotonic()
-                res.update(status=code, latency_s=done - due,
-                           queued_s=env.get("queued_s"),
-                           service_s=env.get("service_s"),
-                           batch_size=env.get("batch_size"),
-                           ok=code == 200 and env.get("status") == "ok",
-                           **_answer(env))
-            except Exception as e:                 # noqa: BLE001
-                res.update(status=-1, ok=False, error=repr(e)[:200])
+            start = t0 + it["due"]
+            res = {"i": it["i"], "late_s": time.monotonic() - start,
+                   "ops": []}
+            for j, op in enumerate(it["ops"]):
+                # the first op keeps the request id r<i> that the metric
+                # readers map to the item; later ops are r<i>.<j>
+                rid = f"r{it['i']}.{j}" if j else f"r{it['i']}"
+                if j:
+                    start = time.monotonic()
+                r = {"path": op["path"]}
+                try:
+                    code, env = cli.post(op["path"], op["body"], rid)
+                    r.update(status=code,
+                             latency_s=time.monotonic() - start,
+                             queued_s=env.get("queued_s"),
+                             service_s=env.get("service_s"),
+                             batch_size=env.get("batch_size"),
+                             ok=code == 200 and env.get("status") == "ok",
+                             **_kept(env, op["keep"]))
+                except Exception as e:             # noqa: BLE001
+                    r.update(status=-1, ok=False, error=repr(e)[:200])
+                res["ops"].append(r)
+                if not r["ok"]:
+                    res["ops"] += [_unsent(o, "not sent")
+                                   for o in it["ops"][j + 1:]]
+                    break
             with lock:
                 results[it["i"]] = res
 
@@ -110,11 +129,12 @@ def run(job: dict) -> dict:
     end_unix = time.time()
     with lock:
         out = [r if r is not None else
-               {"i": it["i"], "status": -1, "ok": False, "error": "missing"}
+               {"i": it["i"], "ops": [_unsent(o, "missing")
+                                      for o in it["ops"]]}
                for r, it in zip(results, items)]
     return {"t0_unix": t0_unix, "end_unix": end_unix,
             "window_s": float(job.get("window_s", 0.0)),
-            "retrieves": out}
+            "items": out}
 
 
 def main() -> int:

@@ -1,4 +1,5 @@
-"""The plain reference and the comparison that decides `correct`.
+"""The plain reference's shared parts, and the verdict that decides
+`correct`.
 
 Imports nothing of the program.  The reference holds its own copy of the
 data (the seeded bulk vectors of bench/data.py) and of the query
@@ -8,16 +9,17 @@ computes in float64:
 
 * dense: exact maximum-inner-product top-k over the query's namespace,
   ties to the lower row id;
-* fuse: weighted reciprocal-rank fusion of that ranking, score
+* fuse: weighted reciprocal-rank fusion of a ranking, score
   w / (60 + rank + 1) in float32 as the served API defines it.
 
-`compare` reads every answer of the window and returns the numbers that
-are held to the configuration's limits.
+Each mix's `compare` (bench/mixes/<kind>.py) reads every answer of the
+window with these and returns the numbers that are held to the
+configuration's limits.
 """
 from __future__ import annotations
 
 import re
-from typing import Dict, List, Sequence
+from typing import Dict, List
 
 import numpy as np
 
@@ -94,53 +96,6 @@ def rrf_scores(k: int, weight: float = 1.0) -> np.ndarray:
     return np.float32(weight) / (np.float32(RRF_C)
                                  + np.arange(k, dtype=np.float32)
                                  + np.float32(1.0))
-
-
-def compare(items: Sequence[dict], answers: Sequence[dict],
-            vectors: np.ndarray, rows_per_tenant: int, k: int,
-            embed: Embedder) -> Dict[str, float]:
-    """Every answer of the window against the reference.
-
-    missing        requests with no answer, or an error for one
-    answer_errors  answers of the wrong length, with a duplicate id or an
-                   id outside the query's namespace, or with a fused score
-                   off the reference's score for its rank by more than two
-                   float32 ulps (the device's division may round apart
-                   from numpy's by one)
-    dense_gap      the widest gap by which a served rank's exact score
-                   lies below the exact score of the reference's row at
-                   that rank, over |q| |x|: 0 for an exact ranking, above
-                   0 only where the ranking swapped rows
-    """
-    missing = answer_errors = 0
-    dense_gap = 0.0
-    for it, ans in zip(items, answers):
-        if not ans.get("ok") or "row_ids" not in ans:
-            missing += 1
-            continue
-        t = it["tenant"]
-        row0 = t * rows_per_tenant
-        rows = vectors[row0: row0 + rows_per_tenant]
-        q = embed(it["body"]["query"])
-        ref, s = exact_topk(rows, row0, q, k)
-        got = np.asarray(ans["row_ids"], np.int64)
-        local = got - row0
-        w = float(it["body"].get("dense_weight", 1.0))
-        want = rrf_scores(ref.size, w).astype(np.float64)
-        scores = np.asarray(ans["scores"], np.float64)
-        if (got.size != ref.size or scores.size != got.size
-                or len(set(got.tolist())) != got.size
-                or np.any(local < 0) or np.any(local >= rows_per_tenant)
-                or np.any(np.abs(scores - want) > 2 ** -22 * want)):
-            answer_errors += 1
-            continue
-        norms = np.linalg.norm(rows.astype(np.float64), axis=1)
-        ref_local = ref - row0
-        gap = (s[ref_local] - s[local]) / (
-            np.linalg.norm(q) * np.maximum(norms[ref_local], norms[local]))
-        dense_gap = max(dense_gap, float(gap.max()))
-    return {"missing": missing, "answer_errors": answer_errors,
-            "dense_gap": dense_gap}
 
 
 def verdict(numbers: Dict[str, float], limits: Dict[str, float]) -> bool:

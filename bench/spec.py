@@ -1,11 +1,14 @@
 """Resolve a benchmark cell by name: BENCHMARK.json -> configuration file,
-traffic file and metric readers under bench/.
+traffic file, mix module and metric readers under bench/.
 
 Everything one configuration, traffic mix or per-layer metric needs sits in
 files of its own, found by the name BENCHMARK.json gives it:
 
     bench/configs/<config>.json     deployment sizes and guarantees
-    bench/traffic/<traffic>.json    parameters of the open-loop mix
+    bench/traffic/<traffic>.json    parameters of the open-loop mix; its
+                                    "mix" key names the kind (default rank)
+    bench/mixes/<kind>.py           the kind's operations, warm-up,
+                                    read-back and reference (MIX_API)
     bench/metrics/<metric>.py       a reader: read(obs) -> float | None
 
 Imports nothing of JAX or of the program, so tests and the load generator
@@ -17,10 +20,16 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+MIX_DIR = BENCH_DIR / "mixes"
+DEFAULT_MIX = "rank"
+# what a mix module defines; all but warm and after_window are numpy-only.
+# An item's retrieve, if it has one, is its first op (traffic.check_items).
+MIX_API = ("schedule", "warm", "after_window", "compare", "live_rows")
 
 
 @dataclasses.dataclass
@@ -31,6 +40,10 @@ class Cell:
     traffic: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+
+    @property
+    def mix(self) -> str:
+        return self.traffic.get("mix", DEFAULT_MIX)
 
 
 def load_benchmark(root: pathlib.Path = ROOT) -> dict:
@@ -62,14 +75,31 @@ def resolve(workload: str, bench: Optional[dict] = None) -> Cell:
     return Cell(workload, int(w["chips"]), config, traffic, e2e, per_layer)
 
 
-def load_reader(metric: str) -> Callable:
-    """bench/metrics/<metric>.py's `read(obs)`."""
-    path = BENCH_DIR / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
+def _load(path: pathlib.Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_mix(kind: str, search: pathlib.Path = MIX_DIR) -> ModuleType:
+    """<search>/<kind>.py, the mix module that defines MIX_API.  Raises
+    KeyError for an unknown kind."""
+    path = search / f"{kind}.py"
+    if not path.is_file():
+        raise KeyError(f"unknown mix {kind!r}; known: "
+                       f"{sorted(p.stem for p in search.glob('*.py'))}")
+    mod = _load(path, f"bench_mix_{kind.replace('.', '_')}")
+    missing = [f for f in MIX_API if not callable(getattr(mod, f, None))]
+    if missing:
+        raise TypeError(f"mix {kind!r} ({path}) does not define {missing}")
+    return mod
+
+
+def load_reader(metric: str) -> Callable:
+    """bench/metrics/<metric>.py's `read(obs)`."""
+    return _load(BENCH_DIR / "metrics" / f"{metric}.py",
+                 f"bench_metric_{metric.replace('.', '_')}").read
 
 
 def load_peaks(device_kind: str) -> Dict[str, float]:

@@ -4,9 +4,11 @@ import os
 import re
 import subprocess
 import sys
+from types import SimpleNamespace
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 import spec  # noqa: E402
@@ -25,8 +27,12 @@ def test_cell_resolves(cell):
     assert len(c.end_to_end) >= 2 and c.per_layer
     for m in c.per_layer:
         assert callable(spec.load_reader(m["name"]))
-    assert set(c.config["limits"]) == {"missing", "answer_errors",
-                                       "dense_gap"}
+    # held to exactly the numbers its mix's check returns (for an empty
+    # window too: the keys do not depend on the items)
+    numbers = spec.load_mix(c.mix).compare([], [], SimpleNamespace(
+        config=c.config, seed=0, after={},
+        vectors=np.zeros((0, c.config["dim"]), np.float32)))
+    assert set(c.config["limits"]) == set(numbers)
 
 
 def test_names_and_keys():

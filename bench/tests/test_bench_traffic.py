@@ -1,5 +1,6 @@
-"""The traffic generator: the same seed gives the same schedule, every
-seed the same amount of work, and large seeds work."""
+"""The traffic generator, through each traffic file's mix: the same seed
+gives the same schedule, every seed the same amount of work, and large
+seeds work."""
 import json
 import os
 import sys
@@ -17,26 +18,28 @@ MIXES = sorted(p.stem for p in (spec.BENCH_DIR / "traffic").glob("*.json"))
 
 
 def _mix(name):
+    """The traffic file's parameters and its mix's schedule."""
     with open(spec.BENCH_DIR / "traffic" / f"{name}.json") as f:
-        return json.load(f)
+        t = json.load(f)
+    return t, spec.load_mix(t.get("mix", spec.DEFAULT_MIX)).schedule
 
 
 @pytest.mark.parametrize("mix", MIXES)
 def test_same_seed_same_schedule(mix):
-    t = _mix(mix)
-    a = traffic.schedule(t, CONFIG, 2 ** 31 + 17, 3.0)
-    b = traffic.schedule(t, CONFIG, 2 ** 31 + 17, 3.0)
+    t, schedule = _mix(mix)
+    a = schedule(t, CONFIG, 2 ** 31 + 17, 3.0)
+    b = schedule(t, CONFIG, 2 ** 31 + 17, 3.0)
     assert json.dumps(a) == json.dumps(b)
-    c = traffic.schedule(t, CONFIG, 2 ** 31 + 18, 3.0)
+    c = schedule(t, CONFIG, 2 ** 31 + 18, 3.0)
     assert json.dumps(a) != json.dumps(c)
 
 
 @pytest.mark.parametrize("mix", MIXES)
 def test_every_seed_same_count_inside_window(mix):
-    t = _mix(mix)
+    t, schedule = _mix(mix)
     n = round(t["rate_per_s"] * 2.0)
     for seed in (0, 1, 5_000_000_000):
-        s = traffic.schedule(t, CONFIG, seed, 2.0)
+        s = schedule(t, CONFIG, seed, 2.0)
         due = np.array([x["due"] for x in s])
         assert len(s) == n
         assert np.all(np.diff(due) >= 0) and 0 <= due[0] and due[-1] < 2.0

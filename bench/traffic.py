@@ -1,14 +1,14 @@
-"""The one traffic generator: a seeded open-loop schedule from a mix's
-parameters (bench/traffic/<name>.json).  Numpy only, so the load-generator
+"""The traffic generator's shared draws, for the mix modules'
+`schedule` (bench/mixes/<kind>.py).  Numpy only, so the load-generator
 process never imports JAX.
 
-A mix file holds:
+A traffic file (bench/traffic/<name>.json) holds the parameters its mix
+reads; every mix reads:
 
+    mix             the mix module's name (absent: "rank")
     rate_per_s      offered arrivals per second (fixed in the cell)
     tenant_skew     Zipf exponent over the configuration's tenants
-    retrieve        the JSON body fields every retrieve carries besides
-                    namespace and query ("stages", "top_k", ...)
-    query           {"templates": [...], "vocab": {field: [words]}}
+    workers         the load generator's connections
 
 Every seed gets the same number of arrivals, rate x seconds, spread over
 the window by exponential gaps (a Poisson process conditioned on its
@@ -23,6 +23,7 @@ import numpy as np
 
 API_KEY = "bench-key"
 TENANT = "bench"
+RETRIEVE = "/v1/retrieve"
 
 
 def bulk_namespace(tenant: int) -> str:
@@ -43,8 +44,8 @@ def zipf_tenants(rng: np.random.Generator, n_tenants: int, skew: float,
     return perm[rng.choice(n_tenants, size=size, p=p)]
 
 
-def _fill(template: str, vocab: Dict[str, List[str]],
-          rng: np.random.Generator) -> str:
+def fill_template(template: str, vocab: Dict[str, List[str]],
+                  rng: np.random.Generator) -> str:
     fields = [f for _, f, _, _ in string.Formatter().parse(template) if f]
     return template.format(**{f: vocab[f][rng.integers(len(vocab[f]))]
                               for f in fields})
@@ -56,23 +57,12 @@ def arrival_times(rng: np.random.Generator, n: int,
     return np.cumsum(gaps[:n]) / gaps.sum() * float(seconds)
 
 
-def schedule(traffic: dict, config: dict, seed: int,
-             seconds: float) -> List[dict]:
-    """The run's requests in due order: dicts with `due` (seconds from the
-    window's start), `tenant` and `body` (the /v1/retrieve JSON)."""
-    rng = np.random.default_rng(seed)
-    n = int(round(float(traffic["rate_per_s"]) * float(seconds)))
-    due = arrival_times(rng, n, seconds)
-    tenants = zipf_tenants(rng, int(config["tenants"]),
-                           float(traffic["tenant_skew"]), n)
-    q = traffic["query"]
-    out = []
-    for i in range(n):
-        ns = bulk_namespace(int(tenants[i]))
-        body = {"namespace": ns,
-                "query": _fill(q["templates"][rng.integers(
-                    len(q["templates"]))], q["vocab"], rng),
-                **traffic["retrieve"]}
-        out.append({"i": i, "due": float(due[i]),
-                    "tenant": int(tenants[i]), "body": body})
-    return out
+def check_items(items: List[dict]) -> None:
+    """Refuse a schedule with a retrieve after an item's first op.  The
+    load generator gives item i's first op the request id r<i>, a later
+    op j r<i>.<j>; the metric readers map r<i> to the item's tenant, so a
+    later retrieve would drop out of their readings unseen."""
+    for it in items:
+        if any(op["path"] == RETRIEVE for op in it["ops"][1:]):
+            raise ValueError(f"item {it['i']} has a {RETRIEVE} op after "
+                             f"its first; a mix's retrieve comes first")
